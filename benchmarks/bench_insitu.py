@@ -48,6 +48,8 @@ _MD: dict = {}
 
 
 def _md_init(roots: dict, parts: dict) -> None:
+    from repro.runtime import pin_to_cpu
+    pin_to_cpu()                  # the bench process may hold the chip
     _MD["roots"], _MD["parts"] = roots, parts
 
 
@@ -196,9 +198,12 @@ def run_device() -> float:
     only the reduced objects, accounted by the engine's
     ``device_stats``. Records the per-step bytes of both paths plus
     their ratio (``insitu.device_transfer_ratio``, acceptance floor 5x)
-    and verifies the reduced catalogs are bit-identical. Returns the
-    transfer ratio.
+    and verifies the device catalog against the host one under the
+    DESIGN §18 contract. Returns the transfer ratio.
     """
+    from repro.insitu.device import cast_like_device, parity_check
+    from repro.insitu.reducers import ReducerDAG
+    from repro.insitu.staging import Snapshot
     tree, _, _ = orion_domains(16, max_level=DEVICE_MAX_LEVEL)
     arrays = tree.to_arrays()
     snap_bytes = sum(v.nbytes for v in arrays.values())
@@ -231,16 +236,18 @@ def run_device() -> float:
             bytes_per_step[mode] = stats.bytes_staged / max(1, n_steps)
         eng.close()
 
-    # correctness: the device catalog must be bit-identical to the host
+    # correctness: the device catalog meets the DESIGN §18 contract
     cat_h, cat_d = Catalog(roots["host"]), Catalog(roots["device"])
     step = cat_h.steps()[-1]
+    dag = ReducerDAG(_live_reducers())
+    cast = dag.run(Snapshot(step=step, kind="amr",
+                            arrays=cast_like_device(arrays)))
     checked = mismatched = 0
-    for reducer in cat_h.reducers(step):
-        a, b = cat_h.query(step, reducer), cat_d.query(step, reducer)
-        for k, v in a.items():
-            checked += 1
-            if not np.array_equal(v, b[k], equal_nan=True):
-                mismatched += 1
+    for r in dag.order:
+        checked += 1
+        err, limit = parity_check(r, cat_d.query(step, r.name),
+                                  cat_h.query(step, r.name), cast[r.name])
+        mismatched += not err <= limit
     cat_h.db.close()
     cat_d.db.close()
     for root in roots.values():
@@ -252,7 +259,7 @@ def run_device() -> float:
     ratio = bytes_per_step["host"] / bytes_per_step["device"]
     emit("insitu.device_bytes_transferred", bytes_per_step["device"],
          f"device->host per step (reduced objects only), snapshot="
-         f"{snap_bytes/1e6:.1f}MB, arrays_checked={checked} "
+         f"{snap_bytes/1e6:.1f}MB, objects_checked={checked} "
          f"mismatched={mismatched}", unit="bytes_per_step",
          repeats=DEVICE_REPS)
     emit("insitu.host_bytes_transferred", bytes_per_step["host"],
@@ -277,30 +284,28 @@ MESH_DEVICES = 4
 MESH_REPS = 3
 
 
-def _mesh_child() -> None:
-    """Child body for :func:`run_mesh` (fresh process: the forced host
-    device count must be in XLA_FLAGS before jax initializes).
+def _mesh_measure() -> dict:
+    """Mesh-vs-single reduce on every visible device (>= MESH_DEVICES).
 
     Runs the 512-res reduction DAG on the deep Orion tree through
-    ``MeshDAGRunner`` at 1 device and at the full forced mesh, checks
-    parity against the host reducers (slice/hist bitwise at the
-    collision-free resolution; projection to the read-side 1e-12 fold
-    contract), and prints one tagged JSON line the parent parses.
+    ``MeshDAGRunner`` at 1 device and at the full mesh and checks every
+    output against the host reducers under the DESIGN §18 contract.
     """
-    import json
-
     import jax
 
+    from repro.insitu.device import cast_like_device, parity_check
     from repro.insitu.mesh_reduce import MeshDAGRunner
     from repro.insitu.reducers import ReducerDAG
     from repro.insitu.staging import Snapshot
 
     ndev = len(jax.devices())
-    assert ndev == MESH_DEVICES, ndev
     tree, _, _ = orion_domains(16, max_level=DEVICE_MAX_LEVEL)
-    snap = Snapshot(step=0, kind="amr", arrays=tree.to_arrays())
+    arrays = tree.to_arrays()
+    snap = Snapshot(step=0, kind="amr", arrays=arrays)
     dag = ReducerDAG(_live_reducers())
     host = dag.run(snap)
+    cast = dag.run(Snapshot(step=0, kind="amr",
+                            arrays=cast_like_device(arrays)))
     out = {}
     for devices in (1, ndev):
         runner = MeshDAGRunner(dag, devices=devices)
@@ -311,15 +316,11 @@ def _mesh_child() -> None:
             res = runner.run(snap)
             best = min(best, time.perf_counter() - t0)
         checked = mismatched = 0
-        for name, o in host.items():
-            for k, v in o.items():
-                got = np.asarray(res[name][k])
-                if name.startswith("proj-"):
-                    ok = bool(np.allclose(got, v, rtol=1e-12, atol=0))
-                else:
-                    ok = np.array_equal(got, v, equal_nan=True)
-                checked += 1
-                mismatched += not ok
+        for r in dag.order:
+            err, limit = parity_check(r, res[r.name], host[r.name],
+                                      cast[r.name])
+            checked += 1
+            mismatched += not err <= limit
         st = runner.stats.as_dict()
         out[str(devices)] = {
             "t": best, "checked": checked, "mismatched": mismatched,
@@ -327,118 +328,89 @@ def _mesh_child() -> None:
             "leaf_rows": st["leaf_rows"],
             "peak_table_mb": st["peak_device_table_bytes"] / 1e6,
             "fallback_snapshots": st["fallback_snapshots"]}
-    print("MESH-JSON " + json.dumps(out), flush=True)
+    return out
 
 
-def run_mesh() -> float:
+def _mesh_child() -> None:
+    """Child body for :func:`run_mesh` on a CPU host (fresh process: the
+    forced host device count must be in XLA_FLAGS before jax
+    initializes); prints one tagged JSON line the parent parses."""
+    import json
+    print("MESH-JSON " + json.dumps(_mesh_measure()), flush=True)
+
+
+def run_mesh() -> float | None:
     """Sharded multi-device reduction vs the single-device path.
 
-    Spawns a child with ``XLA_FLAGS=--xla_force_host_platform_device_
-    count=4`` (the flag must precede jax init, hence the subprocess) and
-    records per-device leaf-table residency
-    (``insitu.mesh_peak_leaf_frac``, CI ceiling 0.6 — the proof that no
-    device ever holds more than ~1/N of the leaf table) and the
-    mesh-vs-single wall-time ratio. On one physical CPU the forced
-    devices timeshare cores, so the ratio documents overhead, not
-    speedup; residency is the acceptance metric. Returns the residency
-    fraction.
+    With at least ``MESH_DEVICES`` devices (a four-chip host) the
+    measurement runs in this process, which already holds them. On a
+    CPU host it spawns a child with ``XLA_FLAGS=--xla_force_host_
+    platform_device_count=4`` (the flag must precede jax init, hence the
+    subprocess). An accelerator host with fewer devices records nothing:
+    a CPU child there would silently measure the wrong device. Records
+    per-device leaf-table residency (``insitu.mesh_peak_leaf_frac``, CI
+    ceiling 0.6 — the proof that no device ever holds more than ~1/N of
+    the leaf table) and the mesh-vs-single wall-time ratio. Returns the
+    residency fraction, or None when not measured.
     """
     import json
     import subprocess
     import sys
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ,
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=%d"
-                        % MESH_DEVICES,
-           "JAX_PLATFORMS": "cpu",
-           "PYTHONPATH": os.pathsep.join(
-               p for p in (os.path.join(root, "src"), root,
-                           os.environ.get("PYTHONPATH")) if p)}
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_insitu", "--mesh-child"],
-        env=env, cwd=root, capture_output=True, text=True, timeout=1200)
-    if proc.returncode != 0:
-        raise RuntimeError(f"mesh bench child failed:\n{proc.stderr[-3000:]}")
-    line = next(ln for ln in proc.stdout.splitlines()
-                if ln.startswith("MESH-JSON "))
-    data = json.loads(line[len("MESH-JSON "):])
+    import jax
+
+    if len(jax.devices()) >= MESH_DEVICES:
+        data = _mesh_measure()
+    elif jax.default_backend() == "cpu":
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {**os.environ,
+               "XLA_FLAGS": "--xla_force_host_platform_device_count=%d"
+                            % MESH_DEVICES,
+               "JAX_PLATFORMS": "cpu",
+               "PYTHONPATH": os.pathsep.join(
+                   p for p in (os.path.join(root, "src"), root,
+                               os.environ.get("PYTHONPATH")) if p)}
+        proc = subprocess.run(
+            [sys.executable, "-m", "benchmarks.bench_insitu",
+             "--mesh-child"],
+            env=env, cwd=root, capture_output=True, text=True,
+            timeout=1200)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"mesh bench child failed:\n{proc.stderr[-3000:]}")
+        line = next(ln for ln in proc.stdout.splitlines()
+                    if ln.startswith("MESH-JSON "))
+        data = json.loads(line[len("MESH-JSON "):])
+    else:
+        print(f"# run_mesh: not measured ({len(jax.devices())} "
+              f"{jax.default_backend()} device(s), needs {MESH_DEVICES})")
+        return None
     bad = {k: v for k, v in data.items()
            if v["mismatched"] or v["fallback_snapshots"]}
     if bad:
         raise AssertionError(f"mesh parity/fallback failure: {bad}")
-    single, mesh = data["1"], data[str(MESH_DEVICES)]
+    devices = jax.devices()
+    single, mesh = data["1"], data[max(data, key=int)]
     frac = mesh["peak_leaf_frac"]
     ratio = single["t"] / mesh["t"]
+    where = (f"{MESH_DEVICES} forced host devices" if len(devices) <
+             MESH_DEVICES else f"{len(devices)} {devices[0].device_kind}")
     emit("insitu.mesh_peak_leaf_frac", frac,
-         f"per-device leaf-table residency at {MESH_DEVICES} forced host "
-         f"devices ({mesh['leaf_rows']} leaf rows, "
+         f"per-device leaf-table residency at {where} "
+         f"({mesh['leaf_rows']} leaf rows, "
          f"{mesh['peak_table_mb']:.1f}MB/device table), 512-res DAG, "
-         f"arrays_checked={mesh['checked']} mismatched=0 (ceiling 0.6)",
+         f"objects_checked={mesh['checked']} mismatched=0 (ceiling 0.6)",
          unit="frac", repeats=MESH_REPS)
     emit("insitu.mesh_vs_single_x", ratio,
-         f"single {single['t']*1e3:.0f}ms vs {MESH_DEVICES}-device mesh "
-         f"{mesh['t']*1e3:.0f}ms per snapshot (forced host devices "
-         f"timeshare one CPU: documents shard_map+merge overhead, "
-         f"not parallel speedup)", unit="x", repeats=MESH_REPS)
+         f"single {single['t']*1e3:.0f}ms vs mesh "
+         f"{mesh['t']*1e3:.0f}ms per snapshot on {where} (forced host "
+         f"devices timeshare one CPU: there it documents shard_map+merge "
+         f"overhead, not parallel speedup)", unit="x", repeats=MESH_REPS)
     emit("insitu.mesh_reduce_step", mesh["t"] * 1e6,
-         f"{MESH_DEVICES}-device shard_map reduce wall per snapshot, "
+         f"shard_map reduce wall per snapshot on {where}, "
          f"merges: psum(hist) ordered-fold(proj) depth-resolve(slice)",
          repeats=MESH_REPS)
     return frac
-
-
-# ------------------------------------------------- ref fusion trajectory
-
-FUSE_REPS = 5
-
-
-def run_ref_fuse() -> float:
-    """CPU ``ref`` slice raster: fused single-traversal vs the pre-PR-9
-    per-level pyramid. Returns the fuse speedup (unfused/fused wall)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ref as ref_kernels
-
-    tree, _, _ = orion_domains(16)
-    arrays = tree.to_arrays()
-    with jax.experimental.enable_x64():
-        offsets = arrays["level_offsets"]
-        n = arrays["refine"].shape[0]
-        levels = jnp.asarray(
-            (np.searchsorted(offsets, np.arange(n), side="right") - 1)
-            .astype(np.int32))
-        coords = arrays["coords"].astype(np.int32)
-        coords2 = jnp.asarray(coords[:, :2])
-        c_axis = jnp.asarray(coords[:, 2])
-        values = jnp.asarray(arrays["field:density"])
-        ok = jnp.asarray(~arrays["refine"])
-        kw = dict(position=0.5, resolution=LIVE_RESOLUTION,
-                  n_levels=int(offsets.shape[0]) - 1)
-        variants = {}
-        for label, fn in (("fused", ref_kernels.slice_raster_ref),
-                          ("unfused", ref_kernels.slice_raster_ref_unfused)):
-            jitted = jax.jit(lambda *a, _f=fn: _f(*a, **kw))
-            img = jax.block_until_ready(
-                jitted(coords2, c_axis, levels, values, ok))  # compile
-            _, t = timeit(lambda: jax.block_until_ready(
-                jitted(coords2, c_axis, levels, values, ok)), reps=FUSE_REPS)
-            variants[label] = (np.asarray(img), t)
-    np.testing.assert_array_equal(variants["fused"][0],
-                                  variants["unfused"][0], err_msg="fuse")
-    t_fused, t_unfused = variants["fused"][1], variants["unfused"][1]
-    emit("insitu.ref_slice_unfused", t_unfused * 1e6,
-         f"per-level pyramid slice raster, {LIVE_RESOLUTION}^2, "
-         f"{tree.n_nodes} nodes", repeats=FUSE_REPS)
-    emit("insitu.ref_slice_fused", t_fused * 1e6,
-         "single-traversal fused slice raster (bitwise-equal image)",
-         repeats=FUSE_REPS)
-    speedup = t_unfused / max(t_fused, 1e-9)
-    emit("insitu.ref_slice_fuse_x", speedup,
-         f"unfused {t_unfused*1e3:.1f}ms / fused {t_fused*1e3:.1f}ms",
-         unit="x", repeats=FUSE_REPS)
-    return speedup
 
 
 # ------------------------------------------------ live lane-backend mode
@@ -781,9 +753,6 @@ def run(n_domains: int = 16, steps: int = 8):
 
     # -------- sharded multi-device reduction (subprocess: forced mesh)
     run_mesh()
-
-    # -------- CPU ref raster fusion trajectory
-    run_ref_fuse()
 
     # -------- telemetry overhead: instrumented vs bare, same engine
     run_obs_overhead()

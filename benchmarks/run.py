@@ -18,6 +18,8 @@ import platform
 import sys
 import traceback
 
+from repro.runtime import setup_compile_cache
+
 from . import common
 
 #: module name -> minimum acceptable ``run()`` return value
@@ -61,6 +63,7 @@ def main(argv=None) -> int:
                    help="comma-separated bench module names "
                         "(e.g. bench_api,bench_insitu)")
     args = p.parse_args(argv)
+    setup_compile_cache()
 
     modules = _modules()
     if args.only:

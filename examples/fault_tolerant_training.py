@@ -31,7 +31,9 @@ def train_cmd(ckpt_dir, steps=14):
 def main():
     shutil.rmtree(CKPT, ignore_errors=True)
     shutil.rmtree(CKPT + "_ref", ignore_errors=True)
-    env = {"PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu"}
+    # children pick their JAX platform from the caller's environment:
+    # this process never touches JAX, so a child may take the chip
+    env = {"PYTHONPATH": SRC}
 
     print("== supervised run with induced (one-off) crash at step 7")
     rc, restarts = run_supervised(train_cmd(CKPT), max_restarts=3, env=env,

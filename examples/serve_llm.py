@@ -11,7 +11,8 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def main():
-    env = {**os.environ, "PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu"}
+    # one child at a time, platform from the caller's environment
+    env = {**os.environ, "PYTHONPATH": SRC}
     for arch in ("stablelm_1_6b", "mamba2_1_3b", "recurrentgemma_2b",
                  "mixtral_8x22b"):
         print(f"== {arch} (smoke config)")

@@ -47,6 +47,7 @@ from ..hercule.database import DomainWriter, HerculeDB, Record
 from ..insitu.staging import ShmStagingArea, StagingArea
 from ..obs import metrics as obs_metrics
 from ..obs.trace import TRACER, Tracer, now_us
+from ..runtime import pin_to_cpu
 
 CKPT_BACKENDS: dict[str, type] = {}
 
@@ -186,6 +187,7 @@ def _ckpt_lane_main(handle, root: str, group: int, results) -> None:
     context), "error" carries the traceback in slot 4, "exit" announces
     a clean drain.
     """
+    pin_to_cpu()                     # the trainer may hold the chip
     area = ShmStagingArea.attach(handle)
     db = HerculeDB.open(root)
     tracer = Tracer(enabled=True)    # only used when _trace rides in

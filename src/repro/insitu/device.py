@@ -14,8 +14,8 @@ remove. This module keeps the snapshot on the accelerator end to end:
     the existing reducer classes to on-device implementations built on
     the Pallas rasterization kernels (``kernels/raster_kernel.py``,
     selected through ``kernels.ops``): axis-aligned slice, projection
-    with owner masking, per-level histogram. Implementations are exact:
-    the reduced objects are bit-identical to the host reducers
+    with owner masking, per-level histogram. Pixel geometry, plane hits
+    and bin assignment are exact; values carry float32 rounding only
     (``tests/test_device_reduce.py``).
   * :class:`DeviceDAGRunner` — executes the engine's ReducerDAG with
     device implementations where registered and a **per-reducer host
@@ -27,10 +27,12 @@ Wired in through ``InTransitEngine(device_reduce=True)``: the thread
 backend stages into :class:`DeviceStagingArea` and lanes run the DAG
 through the runner, so the only steady-state device→host traffic is the
 reduced objects themselves (``bench_insitu.run_device`` records the
-ratio). The whole path runs under ``jax.experimental.enable_x64`` so
-the CPU/interpret kernels see the simulation's float64 exactly; on a
-real TPU the registry would be populated with float32 variants (no f64
-hardware) — documented, not implemented, since CI has no TPU.
+ratio). Device tables are 32-bit on every platform (:func:`device_dtype`:
+float64 fields stage as float32, int64 as int32) because the TPU has no
+float64; the float64 host reducers are the reference, and the outputs
+meet the DESIGN.md §18 tolerances against them (slice 1e-6, projection
+1e-4, histograms exact for the cast values). Kernel and ``ref`` backends
+see the same float32 tables, so they agree bit for bit.
 
 Device impl factories return ``None`` for configs the kernels do not
 cover — non-power-of-two resolutions (the kernels' pixel geometry is
@@ -67,6 +69,91 @@ def _padded(n: int) -> int:
     return -(-n // PAD_BUCKET) * PAD_BUCKET
 
 
+def device_dtype(dtype) -> np.dtype:
+    """The 32-bit dtype a host array takes on the device path."""
+    dtype = np.dtype(dtype)
+    if dtype.kind == "f" and dtype.itemsize > 4:
+        return np.dtype(np.float32)
+    if dtype.kind in "iu" and dtype.itemsize > 4:
+        return np.dtype(np.int32 if dtype.kind == "i" else np.uint32)
+    return dtype
+
+
+def to_device(x, *, copy: bool = False):
+    """``x`` on the device as its :func:`device_dtype` (a new buffer
+    even when it already is one, with ``copy``)."""
+    import jax.numpy as jnp
+    return jnp.array(x, dtype=device_dtype(x.dtype), copy=copy)
+
+
+# --------------------------------------------------- parity contract
+
+def cast_like_device(arrays: dict) -> dict:
+    """Host arrays with every float rounded as the device tables round
+    it (kept in the host dtype): the input of the "exact on the cast
+    values" reference."""
+    out = {}
+    for k, v in arrays.items():
+        v = np.asarray(v)
+        out[k] = v.astype(device_dtype(v.dtype)).astype(v.dtype) \
+            if v.dtype.kind == "f" else v
+    return out
+
+
+def parity_rtol(reducer) -> float | None:
+    """DESIGN.md §18 limit for one reducer's device output.
+
+    Projections (``sum`` merge) accumulate float32 adds: rtol 1e-4
+    against the float64 host reducer. Histograms are exact against the
+    host reducer over the cast values (``None``). Everything else only
+    carries cast values (slices, LOD fields): rtol 1e-6.
+    """
+    return {"sum": 1e-4, "hist": None}.get(reducer.merge, 1e-6)
+
+
+def parity_error(got: dict, want: dict) -> float:
+    """Largest relative error of ``got`` against ``want``, key by key.
+
+    Integer and boolean arrays must match exactly, dtype included, NaN
+    positions must coincide; a mismatch of either is ``inf``.
+    """
+    if set(got) != set(want):
+        return float("inf")
+    err = 0.0
+    for k, w in want.items():
+        g, w = np.asarray(got[k]), np.asarray(w)
+        if g.shape != w.shape:
+            return float("inf")
+        if w.dtype.kind != "f":
+            if g.dtype != w.dtype or not np.array_equal(g, w):
+                return float("inf")
+            continue
+        g, w = g.astype(np.float64), w.astype(np.float64)
+        nan = np.isnan(w)
+        if not np.array_equal(nan, np.isnan(g)):
+            return float("inf")
+        diff = np.abs(g[~nan] - w[~nan])
+        scale = np.abs(w[~nan])
+        if np.any(diff[scale == 0] > 0):
+            return float("inf")
+        rel = diff[scale > 0] / scale[scale > 0]
+        err = max(err, float(rel.max()) if rel.size else 0.0)
+    return err
+
+
+def parity_check(reducer, got: dict, ref: dict, ref_cast: dict):
+    """``(error, limit)`` of a device output under the §18 contract.
+
+    ``ref`` is the host reducer's output on the float64 snapshot,
+    ``ref_cast`` on :func:`cast_like_device` of it; the output passes
+    when ``error <= limit``.
+    """
+    rtol = parity_rtol(reducer)
+    if rtol is None:
+        return parity_error(got, ref_cast), 0.0
+    return parity_error(got, ref), rtol
+
+
 # ------------------------------------------------------- device staging
 
 class _DeviceBufferSet:
@@ -87,22 +174,19 @@ class _DeviceBufferSet:
 
     def fill(self, arrays: dict):
         import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
         out = {}
         reuses = allocs = nbytes = 0
-        with enable_x64():
-            for name, src in arrays.items():
-                # jnp.array (not asarray): a guaranteed copy — device
-                # sources may be donated away by the producer's next
-                # step, host sources may alias on the CPU backend
-                out[name] = jnp.array(src)
-                if isinstance(src, jax.Array):
-                    reuses += 1          # device-resident: no host crossing
-                else:
-                    allocs += 1          # host upload
-                nbytes += out[name].nbytes
-            jax.block_until_ready(out)
+        for name, src in arrays.items():
+            # a guaranteed copy: device sources may be donated away by
+            # the producer's next step, host sources may alias on the
+            # CPU backend
+            out[name] = to_device(src, copy=True)
+            if isinstance(src, jax.Array):
+                reuses += 1          # device-resident: no host crossing
+            else:
+                allocs += 1          # host upload
+            nbytes += out[name].nbytes
+        jax.block_until_ready(out)
         # deliberately NOT retained on self: jax arrays cannot be
         # refilled in place, so holding them while the buffer set sits
         # in the free pool would only pin dead device memory — the
@@ -155,15 +239,15 @@ class DeviceTree:
     def _prep(self):
         if self._geom is None:
             import jax.numpy as jnp
-            refine = jnp.asarray(self.arrays["refine"])
+            refine = to_device(self.arrays["refine"])
             n = int(refine.shape[0])
-            offsets = jnp.asarray(self.arrays["level_offsets"])
+            offsets = to_device(self.arrays["level_offsets"])
             levels = (jnp.searchsorted(offsets, jnp.arange(n), side="right")
                       .astype(jnp.int32) - 1)
             ok = ~refine
             if self.n_domains > 1:   # partitioned: owned leaves count once
-                ok = ok & jnp.asarray(self.arrays["owner"])
-            coords = jnp.asarray(self.arrays["coords"]).astype(jnp.int32)
+                ok = ok & to_device(self.arrays["owner"])
+            coords = to_device(self.arrays["coords"]).astype(jnp.int32)
             self._geom = (self._pad(coords, 0), self._pad(levels, 0),
                           self._pad(ok, False))
         return self._geom
@@ -183,9 +267,8 @@ class DeviceTree:
 
     def field(self, name: str):
         if name not in self._fields:
-            import jax.numpy as jnp
             self._fields[name] = self._pad(
-                jnp.asarray(self.arrays[f"field:{name}"]), 0)
+                to_device(self.arrays[f"field:{name}"]), 0)
         return self._fields[name]
 
 
@@ -259,7 +342,6 @@ def _lod_impl(r: LODCutReducer):
     Kills the last full-snapshot fallback in the default CLI DAG.
     """
     def run(dt: DeviceTree):
-        import jax.numpy as jnp
         offs = np.asarray(dt.arrays["level_offsets"]).astype(np.int64)
         dt.count_to_host(offs.nbytes)
         if len(offs) - 1 <= r.max_level + 1:
@@ -270,12 +352,12 @@ def _lod_impl(r: LODCutReducer):
         n_lv = len(new_offs) - 1
         while n_lv > 1 and new_offs[n_lv] == new_offs[n_lv - 1]:
             n_lv -= 1
-        refine = jnp.asarray(dt.arrays["refine"])[:n_keep]
+        refine = to_device(dt.arrays["refine"])[:n_keep]
         refine = refine.at[int(offs[r.max_level]):n_keep].set(False)
         out = {"refine": refine, "level_offsets": new_offs[:n_lv + 1]}
         for k, v in dt.arrays.items():
             if k not in out and k != "level_offsets":
-                out[k] = jnp.asarray(v)[:n_keep]
+                out[k] = to_device(v)[:n_keep]
         return out
     return run
 
@@ -286,23 +368,24 @@ def _hist_impl(r: LevelHistogramReducer):
         import jax.numpy as jnp
 
         from ..kernels import ops
+        from ..kernels.raster_kernel import split_edges
         v = dt.field(r.field)
         if r.lo is None or r.hi is None:
             # auto bounds: one fused device min/max reduction, a single
-            # 16-byte sync instead of the whole field (or two pulls)
+            # 8-byte sync instead of the whole field (or two pulls)
             mm = np.asarray(jnp.stack(
                 [jnp.min(jnp.where(dt.ok, v, jnp.inf)),
                  jnp.max(jnp.where(dt.ok, v, -jnp.inf))]))
             lo = float(mm[0]) if r.lo is None else r.lo
             hi = float(mm[1]) if r.hi is None else r.hi
-            dt.count_to_host(16)
+            dt.count_to_host(mm.nbytes)
         else:
             lo, hi = r.lo, r.hi
         if hi <= lo:
             hi = lo + 1.0
         edges = np.linspace(lo, hi, r.bins + 1)
-        hist = ops.raster_level_hist(
-            v, dt.levels, dt.ok, jnp.asarray(edges),
+        hist = ops.raster_level_hist_partial(
+            v, dt.levels, dt.ok, jnp.asarray(split_edges(edges)),
             n_levels=min(dt.n_levels, r.max_levels), backend=dt.backend)
         return {"hist": hist, "edges": edges}
     return run
@@ -370,75 +453,77 @@ class DeviceDAGRunner:
 
     def run(self, snap: Snapshot) -> dict[str, dict[str, np.ndarray]]:
         import jax
-        from jax.experimental import enable_x64
-        with enable_x64():
-            outputs: dict[str, dict[str, np.ndarray]] = {}
-            dt = host_snap = None
-            for r in self.dag.order:
-                if snap.kind not in r.kinds:
-                    continue
-                if any(d not in outputs for d in r.deps):
-                    continue
-                impl = self.impls.get(r.name)
-                if impl is not None:
-                    if dt is None:
-                        dt = self._make_view(snap)
-                    moved = 0
-                    out = {}
-                    # spans nest under the lane's open "reduce" span;
-                    # np.asarray is where the async device work lands
+        outputs: dict[str, dict[str, np.ndarray]] = {}
+        dt = host_snap = None
+        for r in self.dag.order:
+            if snap.kind not in r.kinds:
+                continue
+            if any(d not in outputs for d in r.deps):
+                continue
+            impl = self.impls.get(r.name)
+            if impl is not None:
+                if dt is None:
+                    dt = self._make_view(snap)
+                moved = 0
+                out = {}
+                # spans nest under the lane's open "reduce" span;
+                # np.asarray is where the async device work lands
+                with TRACER.span("device.transfer",
+                                 args={"reducer": r.name}) as sp:
+                    for k, v in impl(dt).items():
+                        if isinstance(v, jax.Array):
+                            moved += v.nbytes
+                            v = np.asarray(v)
+                            if v.dtype.kind == "i":
+                                # the host reducers' integer width, so
+                                # catalogs hold one dtype on every path
+                                v = v.astype(np.int64)
+                        out[k] = v
+                    sp.set(nbytes=moved)
+                with self._lock:
+                    self.stats.device_objects += 1
+                    self.stats.bytes_reduced_to_host += moved
+            elif getattr(r, "device_ready", False):
+                # jax-jitted reducers (tensor norms/spectra) consume
+                # device arrays directly; their outputs are already
+                # reduced host arrays
+                out = r.reduce(snap, outputs)
+                with self._lock:
+                    self.stats.device_objects += 1
+                    self.stats.bytes_reduced_to_host += sum(
+                        np.asarray(v).nbytes for v in out.values())
+            elif getattr(r, "source", None):
+                # source-chained reducers only read their upstream's
+                # (already transferred) output — run them on host
+                # without materializing the snapshot
+                out = r.reduce(snap, outputs)
+                with self._lock:
+                    self.stats.fallback_runs[r.name] = \
+                        self.stats.fallback_runs.get(r.name, 0) + 1
+            else:
+                if host_snap is None:
+                    host_arrays, moved = {}, 0
                     with TRACER.span("device.transfer",
-                                     args={"reducer": r.name}) as sp:
-                        for k, v in impl(dt).items():
+                                     args={"reducer": r.name,
+                                           "fallback": True}) as sp:
+                        for k, v in snap.arrays.items():
                             if isinstance(v, jax.Array):
                                 moved += v.nbytes
-                                v = np.asarray(v)
-                            out[k] = v
+                            host_arrays[k] = np.asarray(v)
                         sp.set(nbytes=moved)
+                    host_snap = Snapshot(
+                        step=snap.step, kind=snap.kind,
+                        arrays=host_arrays, meta=snap.meta,
+                        domain=snap.domain, n_domains=snap.n_domains)
                     with self._lock:
-                        self.stats.device_objects += 1
-                        self.stats.bytes_reduced_to_host += moved
-                elif getattr(r, "device_ready", False):
-                    # jax-jitted reducers (tensor norms/spectra) consume
-                    # device arrays directly; their outputs are already
-                    # reduced host arrays
-                    out = r.reduce(snap, outputs)
-                    with self._lock:
-                        self.stats.device_objects += 1
-                        self.stats.bytes_reduced_to_host += sum(
-                            np.asarray(v).nbytes for v in out.values())
-                elif getattr(r, "source", None):
-                    # source-chained reducers only read their upstream's
-                    # (already transferred) output — run them on host
-                    # without materializing the snapshot
-                    out = r.reduce(snap, outputs)
-                    with self._lock:
-                        self.stats.fallback_runs[r.name] = \
-                            self.stats.fallback_runs.get(r.name, 0) + 1
-                else:
-                    if host_snap is None:
-                        host_arrays, moved = {}, 0
-                        with TRACER.span("device.transfer",
-                                         args={"reducer": r.name,
-                                               "fallback": True}) as sp:
-                            for k, v in snap.arrays.items():
-                                if isinstance(v, jax.Array):
-                                    moved += v.nbytes
-                                host_arrays[k] = np.asarray(v)
-                            sp.set(nbytes=moved)
-                        host_snap = Snapshot(
-                            step=snap.step, kind=snap.kind,
-                            arrays=host_arrays, meta=snap.meta,
-                            domain=snap.domain, n_domains=snap.n_domains)
-                        with self._lock:
-                            self.stats.fallback_snapshots += 1
-                            self.stats.bytes_fallback_to_host += moved
-                    out = r.reduce(host_snap, outputs)
-                    with self._lock:
-                        self.stats.fallback_runs[r.name] = \
-                            self.stats.fallback_runs.get(r.name, 0) + 1
-                if out:
-                    outputs[r.name] = out
-            with self._lock:
-                self.stats.snapshots += 1
-            return outputs
+                        self.stats.fallback_snapshots += 1
+                        self.stats.bytes_fallback_to_host += moved
+                out = r.reduce(host_snap, outputs)
+                with self._lock:
+                    self.stats.fallback_runs[r.name] = \
+                        self.stats.fallback_runs.get(r.name, 0) + 1
+            if out:
+                outputs[r.name] = out
+        with self._lock:
+            self.stats.snapshots += 1
+        return outputs

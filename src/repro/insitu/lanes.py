@@ -37,6 +37,7 @@ from ..hercule.database import DomainWriter, HerculeDB, Record
 from ..obs import events as obs_events
 from ..obs import metrics as obs_metrics
 from ..obs.trace import TRACER, Tracer, now_us
+from ..runtime import pin_to_cpu
 from .reducers import ReducerDAG
 from .staging import ShmStagingArea, StagingArea, _CrashSafeCondition
 
@@ -205,6 +206,7 @@ def _lane_main(handle, root: str, group: int, reducers, compress: bool,
     and ships them home in the "done" message — cross-process parent
     linkage with no clock sync beyond the shared epoch.
     """
+    pin_to_cpu()                     # the parent may hold the chip
     area = ShmStagingArea.attach(handle)
     dag = reducers if isinstance(reducers, ReducerDAG) \
         else ReducerDAG(reducers)
@@ -310,6 +312,7 @@ def _pooled_lane_main(task_q, sync, results) -> None:
     rebuilds ride home in the "exit" message (cumulative over this
     lane's lifetime) and surface as ``insitu_lane_dag_*`` metrics.
     """
+    pin_to_cpu()                     # the parent may hold the chip
     dag_cache: dict[str, ReducerDAG] = {}
     stats = {"jobs": 0, "dag_rebuilds": 0, "dag_cache_hits": 0}
     while True:

@@ -34,13 +34,15 @@ counters.
 Bit-parity contract (``tests/test_mesh_reduce.py``): per-shard rows are
 the global BFS-ordered leaves of one Hilbert segment — exactly the
 leaves the multi-domain writer assigns to domain ``g`` — so shard
-partials are bitwise the per-domain host outputs, and the merged images
-are bit-identical to the host reducers for the default float64 tables
-(slice requires ``resolution >= 2**max_level``, where leaf footprints
-are disjoint and painting is collision-free; the read-side tile merge
-has the same contract). ``dtype="float32"`` halves the table uploads
-and trades bit-parity for tolerance parity (DESIGN.md §18: slice rtol
-1e-6, projection rtol 1e-4, histograms exact *for the cast values*).
+partials are bitwise the per-domain device outputs over the same
+float32 tables, and the merged images are bit-identical to the
+read-side merge of those (slice requires ``resolution >= 2**max_level``,
+where leaf footprints are disjoint and painting is collision-free; the
+read-side tile merge has the same contract). Field tables are float32
+on every platform (``device.device_dtype``), so against the float64
+host reducers the contract is tolerance parity (DESIGN.md §18: slice
+rtol 1e-6, projection rtol 1e-4, histograms exact *for the cast
+values*).
 
 Leaf tables larger than the per-shard padded budget (``tile_n``) switch
 to the tiled-gather formulation (``ops`` ``tile_n=``): the shard's rows
@@ -61,10 +63,11 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from .device import DeviceDAGRunner, DeviceRunStats, _padded, _pow2
+from ..kernels.raster_kernel import split_edges
+from .device import (DeviceDAGRunner, DeviceRunStats, _padded, _pow2,
+                     device_dtype)
 from .partition import leaf_shards
 from .reducers import (LevelHistogramReducer, LODCutReducer,
                        ProjectionReducer, ReducerDAG, SliceReducer)
@@ -92,17 +95,16 @@ class MeshTable:
     BFS order, every shard is padded to the common bucket multiple, and
     the stacked ``(S, P, ...)`` arrays are uploaded once under a
     ``NamedSharding`` so device ``g`` receives only shard ``g``'s rows.
-    Fields upload lazily per reducer; ``dtype`` casts them at table
-    build (the f32 variant halves the upload).
+    Fields upload lazily per reducer, cast at table build to their
+    device dtype (float32).
     """
 
     def __init__(self, arrays: dict, n_domains: int, mesh: Mesh, *,
-                 backend: str | None = None, dtype=None,
-                 tile_n: int = MESH_TILE, on_upload=None):
+                 backend: str | None = None, tile_n: int = MESH_TILE,
+                 on_upload=None):
         self.arrays = arrays
         self.mesh = mesh
         self.backend = backend
-        self.dtype = None if dtype is None else np.dtype(dtype)
         self.tile_n = tile_n
         self.on_upload = on_upload or (lambda nbytes: None)
         self.n_shards = int(mesh.devices.size)
@@ -166,27 +168,26 @@ class MeshTable:
         """Valid-row mask: padding rows carry ``ok=False``."""
         return self._prep()[2]
 
+    def _host_field(self, name: str) -> np.ndarray:
+        v = np.asarray(self.arrays[f"field:{name}"])
+        return v.astype(device_dtype(v.dtype))
+
     def field(self, name: str):
         if name not in self._fields:
-            v = np.asarray(self.arrays[f"field:{name}"])
-            if self.dtype is not None:
-                v = v.astype(self.dtype)
+            v = self._host_field(name)
             self._fields[name] = self._shard(
                 self._stack(lambda rows: v[rows], v.dtype, 0))
         return self._fields[name]
 
     def field_bounds(self, name: str) -> tuple[float, float]:
-        """Host-side min/max over the owned leaf values.
+        """Host-side min/max over the owned (cast) leaf values.
 
         min/max are order-free, so this is bitwise the host reducer's
-        auto bounds — and it costs no device pull at all (the staged
-        arrays are host-resident on the mesh path, vs. the single-device
-        path's fused-reduction 16-byte sync). f32 tables bound the
-        *cast* values so the edges match what the kernel bins.
+        auto bounds over the cast values — and it costs no device pull
+        at all (the staged arrays are host-resident on the mesh path,
+        vs. the single-device path's fused-reduction 8-byte sync).
         """
-        v = np.asarray(self.arrays[f"field:{name}"])
-        if self.dtype is not None:
-            v = v.astype(self.dtype)
+        v = self._host_field(name)
         vals = [v[rows] for rows in self._rows if rows.size]
         if not vals:
             return 0.0, 1.0
@@ -269,11 +270,11 @@ def _mesh_slice(coords, levels, ok, values, *, mesh: Mesh, axis: int,
         img, _ = _depth_resolve(img, depth, int(mesh.devices.size))
         return img
 
-    # check_rep=False: the butterfly's ppermute is not *provably*
-    # replicated to the rep checker, though every device holds the same
+    # check_vma=False: the butterfly's ppermute is not *provably*
+    # replicated to the checker, though every device holds the same
     # winner after the last stage
-    f = shard_map(body, mesh=mesh, in_specs=_TBL,
-                  out_specs=PartitionSpec(), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=_TBL,
+                      out_specs=PartitionSpec(), check_vma=False)
     return f(coords, levels, ok, values)
 
 
@@ -290,13 +291,13 @@ def _mesh_projection(coords, levels, ok, values, *, mesh: Mesh, axis: int,
             n_levels=n_levels, backend=backend, tile_n=tile_n)
         return _ordered_sum(img, int(mesh.devices.size))
 
-    f = shard_map(body, mesh=mesh, in_specs=_TBL,
-                  out_specs=PartitionSpec(), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=_TBL,
+                      out_specs=PartitionSpec(), check_vma=False)
     return f(coords, levels, ok, values)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "n_levels", "backend"))
-def _mesh_hist(values, levels, ok, edges, *, mesh: Mesh, n_levels: int,
+def _mesh_hist(values, levels, ok, edges2, *, mesh: Mesh, n_levels: int,
                backend: str | None):
     from ..kernels import ops
 
@@ -305,10 +306,10 @@ def _mesh_hist(values, levels, ok, edges, *, mesh: Mesh, n_levels: int,
             val[0], lv[0], okk[0], e, n_levels=n_levels, backend=backend)
         return jax.lax.psum(hist, MESH_AXIS)
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(*_TBL[:3], PartitionSpec()),
-                  out_specs=PartitionSpec(), check_rep=False)
-    return f(values, levels, ok, edges).astype(jnp.int64)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(*_TBL[:3], PartitionSpec()),
+                      out_specs=PartitionSpec(), check_vma=False)
+    return f(values, levels, ok, edges2)
 
 
 # ----------------------------------------------------- impl registry
@@ -408,7 +409,7 @@ def _hist_mesh(r: LevelHistogramReducer):
             hi = lo + 1.0
         edges = np.linspace(lo, hi, r.bins + 1)
         hist = _mesh_hist(mt.field(r.field), mt.levels, mt.ok,
-                          jnp.asarray(edges), mesh=mt.mesh,
+                          jnp.asarray(split_edges(edges)), mesh=mt.mesh,
                           n_levels=min(mt.n_levels, r.max_levels),
                           backend=mt.backend)
         return {"hist": hist, "edges": edges}
@@ -455,12 +456,10 @@ class MeshDAGRunner(DeviceDAGRunner):
     table is Hilbert-sharded over the first ``devices`` jax devices and
     reduced under ``shard_map``, and host fallbacks cost no device
     traffic (the staged arrays never left the host).
-    ``dtype="float32"`` selects the tolerance-parity table variant.
     """
 
     def __init__(self, dag: ReducerDAG, *, devices: int | None = None,
-                 backend: str | None = None, dtype: str | None = None,
-                 tile_n: int = MESH_TILE):
+                 backend: str | None = None, tile_n: int = MESH_TILE):
         avail = jax.devices()
         n = len(avail) if devices in (None, 0) else int(devices)
         if not 1 <= n <= len(avail):
@@ -470,7 +469,6 @@ class MeshDAGRunner(DeviceDAGRunner):
                 f"XLA_FLAGS=--xla_force_host_platform_device_count=N set "
                 f"before jax initializes)")
         self.mesh = Mesh(np.asarray(avail[:n]), (MESH_AXIS,))
-        self.dtype = dtype
         self.tile_n = tile_n
         super().__init__(dag, backend=backend)
         self.impls = {r.name: mesh_impl_for(r) for r in dag}
@@ -486,8 +484,8 @@ class MeshDAGRunner(DeviceDAGRunner):
 
     def _make_view(self, snap: Snapshot):
         mt = MeshTable(snap.arrays, snap.n_domains, self.mesh,
-                       backend=self.backend, dtype=self.dtype,
-                       tile_n=self.tile_n, on_upload=self._note_upload)
+                       backend=self.backend, tile_n=self.tile_n,
+                       on_upload=self._note_upload)
         with self._lock:
             self.stats.leaf_rows += mt.total_rows
             self.stats.peak_leaf_frac = max(self.stats.peak_leaf_frac,

@@ -157,10 +157,10 @@ def bitfield_unpack(words, n: int, *, backend: str | None = None) -> jnp.ndarray
 # ------------------------------------------------------- AMR rasterization
 #
 # Device-reduction entry points (DESIGN.md §14): each takes flat BFS
-# node arrays — coords (N, 3) int, levels (N,) int, values (N,) float,
+# node arrays — coords (N, 3) int, levels (N,) int, values (N,) float32,
 # ok (N,) bool (leaf ∧ owner ∧ not-padding) — plus the reducer params,
-# and returns the reduced object with bits identical to the host numpy
-# reducers (``insitu.reducers``/``hercule.analysis``). ``resolution``
+# and returns the reduced object with the host numpy reducers' exact
+# geometry (``insitu.reducers``/``hercule.analysis``). ``resolution``
 # must be a power of two (the integer pixel-geometry fast path;
 # ``insitu.device`` falls back to host reducers otherwise). ``ref`` is
 # the fast vectorized CPU path, ``pallas``/``pallas_interpret`` run the
@@ -199,7 +199,7 @@ def raster_slice(coords, levels, values, ok, *, axis: int, position: float,
             coords2, coords[:, axis], levels, values, ok,
             position=position, resolution=resolution, n_levels=n_levels)
     hit = raster_kernel.plane_hit(coords[:, axis], levels, position,
-                                  values.dtype)
+                                  n_levels)
     u0, v0, px = raster_kernel.leaf_table(coords2, levels,
                                           resolution=resolution)
     good = (ok & hit).astype(jnp.int32)
@@ -239,34 +239,15 @@ def raster_projection(coords, levels, values, ok, *, axis: int,
         interpret=(backend == "pallas_interpret"))
 
 
-@functools.partial(jax.jit, static_argnames=("n_levels", "backend",
-                                             "block_n"))
-def raster_level_hist(values, levels, ok, edges, *, n_levels: int,
-                      backend: str | None = None, block_n: int = BLOCK_N):
-    """(n_levels, bins) int64 per-level histogram over ``edges``."""
-    backend = _resolve(backend)
-    levels = levels.astype(jnp.int32)
-    if backend == "ref":
-        hist = ref.level_hist_ref(values, levels, ok, edges,
-                                  n_levels=n_levels)
-    else:
-        hist = raster_kernel.level_hist(
-            _pad_leaf(values, jnp.nan if values.dtype.kind == "f" else 0,
-                      block_n),
-            _pad_leaf(levels, 0, block_n),
-            _pad_leaf(ok.astype(jnp.int32), 0, block_n),
-            edges[None, :], n_levels=n_levels, bins=edges.shape[-1] - 1,
-            block_n=block_n, interpret=(backend == "pallas_interpret"))
-    return hist.astype(jnp.int64)
-
-
 # ------------------------------------------- partial (sharded/tiled) rasters
 #
 # Building blocks for the mesh path (``insitu.mesh_reduce``): rasterize
 # an arbitrary BFS-ordered *subset* of the leaf table into a partial
 # image — callers merge partials on-device (depth-resolve / ordered sum
 # / psum). Unlike the full entry points these are not jitted here: they
-# run inside the caller's ``shard_map``/jit. ``tile_n`` enables the
+# run inside the caller's ``shard_map``/jit. The histogram partial is
+# the exception: jitted, it also serves the single-device path (the
+# whole table is one subset). ``tile_n`` enables the
 # tiled-gather formulation: the table is processed in fixed-size tiles
 # gathered with ``dynamic_slice``, carrying the partial image between
 # tiles — one compiled kernel at the tile shape serves any table length
@@ -310,7 +291,7 @@ def raster_slice_partial(coords, levels, values, ok, *, axis: int,
                 c2, ca, lv, val, okk, position=position,
                 resolution=resolution, n_levels=n_levels,
                 init=(img, depth))
-        hit = raster_kernel.plane_hit(ca, lv, position, val.dtype)
+        hit = raster_kernel.plane_hit(ca, lv, position, n_levels)
         u0, v0, px = raster_kernel.leaf_table(c2, lv, resolution=resolution)
         good = (okk & hit).astype(jnp.int32)
         return raster_kernel.slice_raster_carry(
@@ -359,26 +340,29 @@ def raster_projection_partial(coords, levels, values, ok, *, axis: int,
                       tile_n=tile_n, block_n=block_n)[0]
 
 
-def raster_level_hist_partial(values, levels, ok, edges, *, n_levels: int,
+@functools.partial(jax.jit, static_argnames=("n_levels", "backend",
+                                             "block_n"))
+def raster_level_hist_partial(values, levels, ok, edges2, *, n_levels: int,
                               backend: str | None = None,
                               block_n: int = BLOCK_N):
     """Partial per-level histogram: (L, B) int32 counts for a subset.
 
-    Integer counts are order-free, so partials merge with ``psum``. No
+    ``edges2`` is the (2, bins+1) :func:`raster_kernel.split_edges` form
+    of the float64 bin edges. Integer counts are order-free, so partials merge with ``psum``. No
     ``tile_n``: the kernel's grid already streams the table block by
     block with an O(L·B) working set.
     """
     backend = _resolve(backend)
     levels = levels.astype(jnp.int32)
     if backend == "ref":
-        return ref.level_hist_ref(values, levels, ok, edges,
+        return ref.level_hist_ref(values, levels, ok, edges2,
                                   n_levels=n_levels)
     return raster_kernel.level_hist(
         _pad_leaf(values, jnp.nan if values.dtype.kind == "f" else 0,
                   block_n),
         _pad_leaf(levels, 0, block_n),
         _pad_leaf(ok.astype(jnp.int32), 0, block_n),
-        edges[None, :], n_levels=n_levels, bins=edges.shape[-1] - 1,
+        edges2, n_levels=n_levels, bins=edges2.shape[-1] - 1,
         block_n=block_n, interpret=(backend == "pallas_interpret"))
 
 

@@ -16,18 +16,26 @@ l)`` reproduce the host reducers' float ``floor``/``round`` results bit
 for bit; non-pow2 resolutions take the host fallback in
 ``insitu.device``.
 
-Kernel shape: leaves ride the lane axis in ``(1, BLOCK_N)`` tables; the
-grid walks leaf blocks *sequentially* while the full output image (or
-histogram) stays resident in VMEM across grid steps (constant
-``index_map``, initialized on the first step). Inside a block the
-slice/projection kernels ``fori_loop`` over leaves, each iteration
-updating the output tile through a broadcast rectangle mask — masked
-``where`` updates, never scatter, so per-pixel update *order* equals
-the host reducers' BFS traversal and float accumulation is
-bit-identical, not just close. The histogram kernel is fully
-vectorized: a (BLOCK, B+1) edge-compare reproduces
-``np.searchsorted(edges, v, "right")`` and a (BLOCK, L·B) one-hot
-contraction is the blocked scatter-add (integer counts — order-free).
+Kernel shape: the grid walks leaf blocks *sequentially* while the full
+output image (or histogram) stays resident in VMEM across grid steps
+(constant ``index_map``, initialized on the first step). Inside a block
+the slice/projection kernels ``fori_loop`` over leaves, reading each
+leaf's scalars from ``(1, BLOCK_N)`` tables in SMEM (vector memory
+cannot serve a per-leaf dynamic lane index), and update the image
+through a broadcast rectangle mask — masked ``where`` updates, never
+scatter, so per-pixel update *order* equals the host reducers' BFS
+traversal. At R=512 the carry kernels' planes fit the default scoped
+VMEM, so no ``vmem_limit_bytes`` is set. The histogram kernel is fully
+vectorized: leaves ride the lane axis, a (B+1, BLOCK) edge-compare
+against float64 edges split into float32 pairs (:func:`split_edges`)
+reproduces ``np.searchsorted(edges, v, "right")``, and an MXU
+contraction of level and bin one-hots is the blocked scatter-add
+(integer counts — order-free).
+
+Tables are float32 (no float64 on the TPU): the kernels and the ``ref``
+twins agree bit for bit on them, and against the float64 host reducers
+they meet the DESIGN.md §18 tolerances (geometry, plane hits and bin
+assignment exact; values carry float32 rounding).
 
 Like the fpdelta kernels, every entry point takes ``interpret=`` so CPU
 CI exercises the exact kernel path (``backend="pallas_interpret"`` in
@@ -38,10 +46,13 @@ ordering argument (XLA CPU applies scatter updates in order).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: leaves per grid step (lane-dim multiple of 128)
 DEFAULT_BLOCK_N = 512
@@ -68,18 +79,62 @@ def leaf_table(coords, levels, *, resolution: int):
     return u0, v0, px
 
 
-def plane_hit(coords_axis, levels, position: float, dtype):
-    """Host-exact slice-plane test: ``lo <= position < lo + size``.
+def plane_cells(position: float, n_levels: int) -> np.ndarray:
+    """Per level ``l``, the cell index the plane crosses: ``floor(pos·2^l)``.
 
-    Both bounds are exact dyadic rationals in float64 (c/2^l), so the
-    comparison reproduces ``analysis.slice_image`` bit for bit.
+    Host float64 and exact (scaling by ``2^l`` is exact), so
+    ``c == cells[l]`` is the host slicer's ``c/2^l <= pos < (c+1)/2^l``
+    bit for bit whatever dtype the device tables use. Clamped to
+    ``[-1, 2^l]`` (no cell) so it fits int32.
     """
-    size = jnp.asarray(2.0, dtype) ** (-levels.astype(dtype))
-    lo = coords_axis.astype(dtype) * size
-    return (lo <= position) & (position < lo + size)
+    return np.array([min(max(math.floor(position * (1 << lvl)), -1),
+                         1 << lvl) for lvl in range(n_levels)], np.int32)
+
+
+def plane_hit(coords_axis, levels, position: float, n_levels: int):
+    """Host-exact slice-plane test as integer arithmetic (no float)."""
+    cells = jnp.asarray(plane_cells(position, n_levels))
+    lvl = jnp.clip(levels.astype(jnp.int32), 0, n_levels - 1)
+    return coords_axis.astype(jnp.int32) == cells[lvl]
+
+
+def split_edges(edges) -> np.ndarray:
+    """(2, B+1) float32 form of float64 bin edges: rounding + residual sign.
+
+    Row 0 is each edge rounded to float32, row 1 the sign of what the
+    rounding dropped. For a float32 value ``v`` the pair decides
+    ``edge <= v`` exactly (:func:`edge_le`), so float32 tables bin
+    against the host reducer's float64 edges with no rounding of the
+    edges themselves.
+    """
+    e = np.asarray(edges, np.float64)
+    hi = e.astype(np.float32)
+    sgn = np.sign(e - hi.astype(np.float64)).astype(np.float32)
+    return np.stack([hi, sgn])
+
+
+def edge_le(hi, sgn, v):
+    """``edge <= v`` for float64 edges given as :func:`split_edges` rows."""
+    return (hi < v) | ((hi == v) & (sgn <= 0))
+
+
+def v_le_edge(hi, sgn, v):
+    """``v <= edge`` for float64 edges given as :func:`split_edges` rows."""
+    return (v < hi) | ((v == hi) & (sgn >= 0))
 
 
 # ------------------------------------------------------------ slice kernel
+
+def _smem_table(block_n: int):
+    """Leaf-table block in scalar memory.
+
+    The raster loops read one scalar per leaf at a dynamic index; vector
+    memory only serves lane-aligned loads, so the per-leaf tables ride
+    SMEM (one ``(1, block_n)`` block per grid step).
+    """
+    return pl.BlockSpec((1, block_n), lambda i: (0, i),
+                        memory_space=pltpu.SMEM)
+
 
 def _slice_body(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
                 img_ref, depth_ref, *, block_n: int, resolution: int):
@@ -144,7 +199,7 @@ def slice_raster(u0, v0, px, lvl, val, ok, *, resolution: int,
     n = u0.shape[-1]
     assert n % block_n == 0, f"N={n} not padded to {block_n}"
     grid = (n // block_n,)
-    tbl = pl.BlockSpec((1, block_n), lambda i: (0, i))
+    tbl = _smem_table(block_n)
     out = pl.BlockSpec((resolution, resolution), lambda i: (0, 0))
     img, _ = pl.pallas_call(
         functools.partial(_slice_kernel, block_n=block_n,
@@ -177,7 +232,7 @@ def slice_raster_carry(u0, v0, px, lvl, val, ok, img0, depth0, *,
     n = u0.shape[-1]
     assert n % block_n == 0, f"N={n} not padded to {block_n}"
     grid = (n // block_n,)
-    tbl = pl.BlockSpec((1, block_n), lambda i: (0, i))
+    tbl = _smem_table(block_n)
     out = pl.BlockSpec((resolution, resolution), lambda i: (0, 0))
     img, depth = pl.pallas_call(
         functools.partial(_slice_carry_kernel, block_n=block_n,
@@ -248,7 +303,7 @@ def projection_raster(u0, v0, px, contrib, ok, *, resolution: int,
     n = u0.shape[-1]
     assert n % block_n == 0, f"N={n} not padded to {block_n}"
     grid = (n // block_n,)
-    tbl = pl.BlockSpec((1, block_n), lambda i: (0, i))
+    tbl = _smem_table(block_n)
     out = pl.BlockSpec((resolution, resolution), lambda i: (0, 0))
     return pl.pallas_call(
         functools.partial(_proj_kernel, block_n=block_n,
@@ -276,7 +331,7 @@ def projection_raster_carry(u0, v0, px, contrib, ok, img0, *,
     n = u0.shape[-1]
     assert n % block_n == 0, f"N={n} not padded to {block_n}"
     grid = (n // block_n,)
-    tbl = pl.BlockSpec((1, block_n), lambda i: (0, i))
+    tbl = _smem_table(block_n)
     out = pl.BlockSpec((resolution, resolution), lambda i: (0, 0))
     return pl.pallas_call(
         functools.partial(_proj_carry_kernel, block_n=block_n,
@@ -292,49 +347,60 @@ def projection_raster_carry(u0, v0, px, contrib, ok, img0, *,
 
 # -------------------------------------------------------- histogram kernel
 
-def _hist_kernel(val_ref, lvl_ref, ok_ref, edges_ref, hist_ref, *,
+def _hist_kernel(val_ref, lvl_ref, ok_ref, ehi_ref, esgn_ref, hist_ref, *,
                  n_levels: int, bins: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        hist_ref[...] = jnp.zeros((n_levels, bins), jnp.int32)
+        hist_ref[...] = jnp.zeros(hist_ref.shape, jnp.int32)
 
-    v = val_ref[0, :]                       # (BLOCK,)
-    lvl = lvl_ref[0, :].astype(jnp.int32)
-    edges = edges_ref[0, :]                 # (bins + 1,)
-    # searchsorted(edges, v, side="right") == #edges <= v, vectorized as
-    # an edge-compare reduction (no in-kernel gather/scatter)
-    idx = jnp.sum((edges[:, None] <= v[None, :]).astype(jnp.int32),
-                  axis=0, dtype=jnp.int32) - 1
-    b = jnp.where(v == edges[-1], bins - 1, idx)    # top edge inclusive
-    good = ((ok_ref[0, :] != 0) & (v >= edges[0]) & (v <= edges[-1])
+    v = val_ref[...]                        # (1, BLOCK) leaves on lanes
+    lvl = lvl_ref[...]
+    ehi, esgn = ehi_ref[...], esgn_ref[...]     # (bins + 1, 1) columns
+    # searchsorted(edges, v, side="right") == #edges <= v, as an
+    # edge-compare reduction over sublanes (no in-kernel gather)
+    ge = edge_le(ehi, esgn, v).astype(jnp.int32)          # (B+1, BLOCK)
+    idx = jnp.sum(ge, axis=0, keepdims=True) - 1
+    b = jnp.minimum(idx, bins - 1)                 # top edge inclusive
+    good = ((ok_ref[...] != 0) & (ge[0:1, :] != 0)
+            & v_le_edge(ehi[bins:, :], esgn[bins:, :], v)
             & (lvl >= 0) & (lvl < n_levels))
-    flat = jnp.where(good, lvl * bins + b, -1)      # (BLOCK,)
-    cells = jax.lax.broadcasted_iota(jnp.int32, (1, n_levels * bins), 1)
-    onehot = (flat[:, None] == cells).astype(jnp.int32)   # (BLOCK, L*B)
-    hist_ref[...] = hist_ref[...] + jnp.sum(
-        onehot, axis=0, dtype=jnp.int32).reshape(n_levels, bins)
+    # blocked scatter-add as one MXU contraction over the leaf axis:
+    # (L, BLOCK) level one-hot x (B, BLOCK) bin one-hot -> (L, B) counts;
+    # 0/1 operands and <= BLOCK-term sums are exact in bf16 x f32
+    lp, bp = hist_ref.shape
+    lv_hot = ((jax.lax.broadcasted_iota(jnp.int32, (lp, v.shape[1]), 0)
+               == lvl) & good).astype(jnp.bfloat16)
+    b_hot = (jax.lax.broadcasted_iota(jnp.int32, (bp, v.shape[1]), 0)
+             == b).astype(jnp.bfloat16)
+    counts = jax.lax.dot_general(lv_hot, b_hot, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    hist_ref[...] = hist_ref[...] + counts.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_levels", "bins", "block_n",
                                              "interpret"))
-def level_hist(val, lvl, ok, edges, *, n_levels: int, bins: int,
+def level_hist(val, lvl, ok, edges2, *, n_levels: int, bins: int,
                block_n: int = DEFAULT_BLOCK_N, interpret: bool = False):
-    """(L, B) per-level histogram via blocked one-hot scatter-add.
+    """(L, B) per-level histogram via blocked one-hot contraction.
 
-    Bin assignment reproduces ``np.histogram(v, bins=edges)`` exactly
-    (right-open bins, top edge inclusive, out-of-range excluded);
-    integer counts make accumulation order-free.
+    ``edges2`` is the (2, B+1) :func:`split_edges` form of the float64
+    edges. Bin assignment reproduces ``np.histogram(v, bins=edges)``
+    exactly (right-open bins, top edge inclusive, out-of-range
+    excluded); integer counts make accumulation order-free. The
+    accumulator is padded to whole (8, 128) tiles and cut on return.
     """
     n = val.shape[-1]
     assert n % block_n == 0, f"N={n} not padded to {block_n}"
     grid = (n // block_n,)
     tbl = pl.BlockSpec((1, block_n), lambda i: (0, i))
-    return pl.pallas_call(
+    col = pl.BlockSpec((bins + 1, 1), lambda i: (0, 0))
+    lp, bp = -(-n_levels // 8) * 8, -(-bins // 128) * 128
+    hist = pl.pallas_call(
         functools.partial(_hist_kernel, n_levels=n_levels, bins=bins),
         grid=grid,
-        in_specs=[tbl, tbl, tbl,
-                  pl.BlockSpec((1, edges.shape[-1]), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((n_levels, bins), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_levels, bins), jnp.int32),
+        in_specs=[tbl, tbl, tbl, col, col],
+        out_specs=pl.BlockSpec((lp, bp), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((lp, bp), jnp.int32),
         interpret=interpret,
-    )(val, lvl, ok, edges)
+    )(val, lvl, ok, edges2[0][:, None], edges2[1][:, None])
+    return hist[:n_levels, :bins]

@@ -3,6 +3,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .raster_kernel import edge_le, plane_hit, v_le_edge
+
 
 def clz32_ref(x: jnp.ndarray) -> jnp.ndarray:
     """Count leading zeros of uint32 via bit-smear + SWAR popcount.
@@ -84,42 +86,6 @@ def _level_pix(coords2, resolution: int, lvl: int):
     return u0 * resolution + v0
 
 
-def slice_raster_ref_unfused(coords2, c_axis, levels, values, ok, *,
-                             position: float, resolution: int,
-                             n_levels: int):
-    """Per-level-scatter slice oracle (the pre-fusion formulation).
-
-    Kept for the bench's before/after record and as a parity
-    cross-check: every level runs a full-table scatter, so the cost is
-    ``n_levels`` sequential passes over all N rows —
-    :func:`slice_raster_ref` fuses them into one.
-    """
-    r = resolution
-    k = r.bit_length() - 1
-    img = jnp.full((r, r), jnp.nan, values.dtype)
-    for lvl in range(n_levels):
-        size = 1.0 / (1 << lvl)
-        lo = c_axis.astype(values.dtype) * size
-        sel = ok & (levels == lvl) & (lo <= position) & (position < lo + size)
-        if lvl <= k:
-            g, px = 1 << lvl, r >> lvl
-            idx = jnp.where(sel, coords2[:, 0] * g + coords2[:, 1], g * g)
-            coarse = jnp.full(g * g + 1, jnp.nan, values.dtype
-                              ).at[idx].set(values)
-            painted = jnp.zeros(g * g + 1, bool).at[idx].set(sel)
-            up_val = jnp.repeat(jnp.repeat(coarse[:-1].reshape(g, g),
-                                           px, 0), px, 1)
-            up_hit = jnp.repeat(jnp.repeat(painted[:-1].reshape(g, g),
-                                           px, 0), px, 1)
-            img = jnp.where(up_hit, up_val, img)
-        else:
-            idx = jnp.where(sel, _level_pix(coords2, r, lvl), r * r)
-            flat = jnp.concatenate(
-                [img.reshape(-1), jnp.zeros(1, values.dtype)])
-            img = flat.at[idx].set(values)[:-1].reshape(r, r)
-    return img
-
-
 def _slice_pyramid(coords2, c_axis, levels, values, ok, *,
                    position: float, resolution: int, n_levels: int):
     """One fused scatter of every node into a per-level pyramid buffer.
@@ -141,9 +107,7 @@ def _slice_pyramid(coords2, c_axis, levels, values, ok, *,
         bases.append(off)
         off += g * g
     lvl32 = levels.astype(jnp.int32)
-    size = jnp.asarray(2.0, values.dtype) ** (-lvl32.astype(values.dtype))
-    lo = c_axis.astype(values.dtype) * size
-    sel = (ok & (lo <= position) & (position < lo + size)
+    sel = (ok & plane_hit(c_axis, lvl32, position, n_levels)
            & (lvl32 >= 0) & (lvl32 < n_levels))
     safe = jnp.clip(lvl32, 0, n_levels - 1)
     dn = jnp.maximum(safe - k, 0)
@@ -263,12 +227,17 @@ def projection_raster_ref(coords2, levels, values, ok, *,
     return img
 
 
-def level_hist_ref(values, levels, ok, edges, *, n_levels: int):
-    """Oracle for the histogram kernel (np.histogram bin semantics)."""
-    bins = edges.shape[-1] - 1
-    idx = jnp.searchsorted(edges, values, side="right") - 1
-    b = jnp.where(values == edges[-1], bins - 1, idx)
-    good = (ok & (values >= edges[0]) & (values <= edges[-1])
+def level_hist_ref(values, levels, ok, edges2, *, n_levels: int):
+    """Oracle for the histogram kernel (np.histogram bin semantics).
+
+    ``edges2`` is the :func:`raster_kernel.split_edges` pair, so float32
+    values bin against the float64 edges exactly, as in the kernel.
+    """
+    hi, sgn = edges2[0], edges2[1]
+    bins = hi.shape[-1] - 1
+    ge = edge_le(hi[None, :], sgn[None, :], values[:, None])   # (N, B+1)
+    b = jnp.minimum(jnp.sum(ge, axis=1, dtype=jnp.int32) - 1, bins - 1)
+    good = (ok & ge[:, 0] & v_le_edge(hi[-1], sgn[-1], values)
             & (levels >= 0) & (levels < n_levels))
     flat = jnp.where(good, levels * bins + b, n_levels * bins)
     hist = jnp.zeros(n_levels * bins + 1, jnp.int32).at[flat].add(

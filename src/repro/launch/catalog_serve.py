@@ -32,6 +32,8 @@ import time
 
 import numpy as np
 
+from ..runtime import setup_compile_cache
+
 
 def _make_demo_db(root: str, *, domains: int = 2, steps: int = 2) -> None:
     """Small Sedov-based 2-domain in-transit database for the selftest."""
@@ -368,6 +370,7 @@ def main(argv=None):
     p.add_argument("--ledger-interval", type=float, default=5.0,
                    help="seconds between background ledger flushes")
     args = p.parse_args(argv)
+    setup_compile_cache()
 
     import os
     token = args.token if args.token is not None \

@@ -15,6 +15,7 @@ import numpy as np
 
 from ..insitu import (Catalog, InTransitEngine, LevelHistogramReducer,
                       LODCutReducer, ProjectionReducer, SliceReducer)
+from ..runtime import setup_compile_cache
 from ..sim import amrgen, fields
 
 
@@ -87,6 +88,7 @@ def main(argv=None):
                    help="seconds between background ledger flushes "
                         "(0 = flush only at exit)")
     args = p.parse_args(argv)
+    setup_compile_cache()
 
     if args.trace_out or args.ledger:
         from ..obs import TRACER
@@ -140,6 +142,14 @@ def main(argv=None):
         print(f"   staging[g{g}]: accepted={stats.accepted} "
               f"evicted={stats.evicted} dropped={stats.dropped} "
               f"reuses={stats.buffer_reuses} allocs={stats.buffer_allocs}")
+    # every default reducer type has a device impl, so a snapshot pulled
+    # to the host means one of them ran there (e.g. a non-pow2
+    # --resolution); source-chained reducers never pull the snapshot
+    host_fallback = bool(device_reduce) and \
+        engine.device_stats["fallback_snapshots"] > 0
+    if host_fallback:
+        print(f"   ERROR: snapshots reduced on the host: "
+              f"fallback_runs={engine.device_stats['fallback_runs']}")
     if args.device_reduce:
         ds = engine.device_stats
         staged = sum(a.stats.bytes_staged for a in engine.stages)
@@ -258,7 +268,7 @@ def main(argv=None):
     stride = max(1, img.shape[0] // 24)
     for row in chars[::stride]:
         print("   " + "".join(row[::max(1, stride // 2)]))
-    return 0
+    return 1 if host_fallback else 0
 
 
 if __name__ == "__main__":

@@ -10,6 +10,7 @@ import argparse
 from ..configs import ARCHS, get_config, get_smoke_config
 from ..data.pipeline import DataConfig
 from ..models.transformer import LM
+from ..runtime import setup_compile_cache
 from ..train import optim
 from ..train.trainer import Trainer
 
@@ -76,6 +77,7 @@ def main(argv=None):
                         "trainer process on this port (0 = ephemeral)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     lm = LM(cfg)

@@ -1,19 +1,22 @@
 """On-accelerator reduction subsystem (DESIGN.md §14).
 
 Kernel parity is the acceptance criterion: for slice / projection /
-per-level histogram, ``pallas_interpret`` == ``ref`` == host-numpy
-reducer outputs, bit for bit, on random AMR trees — including
-owner-masked partitioned inputs. Plus device staging semantics
-(device-resident snapshots, push-copy safety, backpressure parity) and
-the end-to-end ``InTransitEngine(device_reduce=True)`` path (bit-equal
-catalogs, host fallback for unregistered reducers, transfer accounting).
+per-level histogram, ``pallas_interpret`` == ``ref`` bit for bit (both
+see the same float32 device tables), and both meet the DESIGN.md §18
+tolerances against the float64 host-numpy reducers on random AMR trees
+— including owner-masked partitioned inputs. Plus device staging
+semantics (device-resident 32-bit snapshots, push-copy safety,
+backpressure parity) and the end-to-end
+``InTransitEngine(device_reduce=True)`` path (§18-equal catalogs, host
+fallback for unregistered reducers, transfer accounting).
 """
 import numpy as np
 import pytest
 
 from repro.insitu import Catalog, InTransitEngine, partition_snapshot
 from repro.insitu.device import (DeviceDAGRunner, DeviceStagingArea,
-                                 device_impl_for)
+                                 cast_like_device, device_impl_for,
+                                 parity_check)
 from repro.insitu.reducers import (LevelHistogramReducer, LODCutReducer,
                                    ProjectionReducer, ReducerDAG,
                                    SliceReducer)
@@ -50,21 +53,38 @@ def assert_tree_equal(a: dict, b: dict):
         np.testing.assert_array_equal(a[k], b[k], err_msg=k)
 
 
+def assert_parity(dag, got: dict, arrays: dict, n_domains: int = 1,
+                  domain: int = 0):
+    """Every device output within its §18 limit of the host reducers."""
+    def host(a):
+        return dag.run(Snapshot(step=0, kind="amr", arrays=a, domain=domain,
+                                n_domains=n_domains))
+    ref, ref_cast = host(arrays), host(cast_like_device(arrays))
+    assert set(got) == set(ref)
+    for r in dag.order:
+        err, limit = parity_check(r, got[r.name], ref[r.name],
+                                  ref_cast[r.name])
+        assert err <= limit, (r.name, err, limit)
+
+
 # ------------------------------------------------------------ kernel parity
 
 @pytest.mark.parametrize("seed", SEEDS)
 @pytest.mark.parametrize("resolution", RESOLUTIONS)
 def test_kernel_parity_single_domain(seed, resolution):
-    """pallas_interpret == ref == host reducers, bit for bit."""
+    """pallas_interpret == ref bit for bit; both within §18 of host."""
     tree = random_tree(seed)
-    snap = Snapshot(step=0, kind="amr", arrays=tree.to_arrays())
+    arrays = tree.to_arrays()
+    snap = Snapshot(step=0, kind="amr", arrays=arrays)
     dag, host = host_outputs(snap, resolution)
+    dev = {}
     for backend in ("ref", "pallas_interpret"):
         runner = DeviceDAGRunner(dag, backend=backend)
-        dev = runner.run(snap)
+        dev[backend] = runner.run(snap)
         assert not runner.stats.fallback_runs
-        for rname in host:
-            assert_tree_equal(host[rname], dev[rname])
+    for rname in host:
+        assert_tree_equal(dev["ref"][rname], dev["pallas_interpret"][rname])
+    assert_parity(dag, dev["pallas_interpret"], arrays)
 
 
 @pytest.mark.parametrize("backend", ["ref", "pallas_interpret"])
@@ -82,10 +102,8 @@ def test_kernel_parity_owner_masked_partitions(backend):
     for g, part in enumerate(parts):
         snap = Snapshot(step=0, kind="amr", arrays=part, domain=g,
                         n_domains=len(parts))
-        host = dag.run(snap)
-        dev = runner.run(snap)
-        for rname in host:
-            assert_tree_equal(host[rname], dev[rname])
+        assert_parity(dag, runner.run(snap), part, n_domains=len(parts),
+                      domain=g)
 
 
 def test_device_impl_registry_fallback_configs():
@@ -114,7 +132,7 @@ def test_device_staging_holds_jax_arrays_and_copies():
     a[:] = -1.0
     snap = st.pop(timeout=1.0)
     assert isinstance(snap.arrays["a"], jax.Array)
-    assert snap.arrays["a"].dtype == np.float64   # x64 staging, no downcast
+    assert snap.arrays["a"].dtype == np.float32   # 32-bit device tables
     np.testing.assert_array_equal(np.asarray(snap.arrays["a"]),
                                   np.arange(8.0))
     st.release(snap)
@@ -156,10 +174,11 @@ def test_device_staging_drop_oldest_parity():
 # ------------------------------------------------------------- engine e2e
 
 def test_engine_device_reduce_bit_identical(tmp_path):
-    """device_reduce=True writes a catalog bit-identical to the host
-    path, transfers less than the full snapshot, and never materializes
-    a full snapshot on host (every default reducer has a device impl
-    since the PR 9 LOD cut)."""
+    """device_reduce=True writes a catalog within the §18 contract of
+    the host path (exact for histograms on the cast values), transfers
+    less than the full snapshot, and never materializes a full snapshot
+    on host (every default reducer has a device impl, the LOD cut
+    included)."""
     tree = random_tree(11)
     mk = lambda: [  # noqa: E731
         SliceReducer(field="density", resolution=64),
@@ -184,10 +203,15 @@ def test_engine_device_reduce_bit_identical(tmp_path):
         else:
             assert eng.device_stats is None
     ch, cd = Catalog(roots[False]), Catalog(roots[True])
+    dag = ReducerDAG(mk())
+    cast = dag.run(Snapshot(step=0, kind="amr",
+                            arrays=cast_like_device(tree.to_arrays())))
     for s in (1, 2):
         assert ch.reducers(s) == cd.reducers(s)
-        for r in ch.reducers(s):
-            assert_tree_equal(ch.query(s, r), cd.query(s, r))
+        for r in dag.order:
+            err, limit = parity_check(r, cd.query(s, r.name),
+                                      ch.query(s, r.name), cast[r.name])
+            assert err <= limit, (r.name, err, limit)
     ch.close()
     cd.close()
 
@@ -214,45 +238,51 @@ def test_engine_device_reduce_transfer_savings(tmp_path):
 
 def test_engine_device_reduce_multidomain_merge(tmp_path):
     """device_reduce composes with contributor groups: per-domain device
-    parts are bit-identical to the host multi-domain path, and the
-    merged answers agree with the single-domain reference."""
+    parts meet the §18 contract against the host multi-domain path, and
+    the merged answers agree with the single-domain reference."""
     tree = random_tree(9)
+    arrays = tree.to_arrays()
     mk = lambda: [  # noqa: E731
         ProjectionReducer(field="density", resolution=32),
         LevelHistogramReducer(field="density", bins=16, lo=-8.0, hi=8.0),
     ]
     roots = {}
-    for name, domains, dev in (("ref", 1, True), ("md_host", 2, False),
-                               ("md_dev", 2, True)):
+    for name, domains, dev, src in (
+            ("ref", 1, True, arrays), ("md_host", 2, False, arrays),
+            ("md_cast", 2, False, cast_like_device(arrays)),
+            ("md_dev", 2, True, arrays)):
         roots[name] = str(tmp_path / name)
         eng = InTransitEngine(roots[name], mk(), domains=domains,
                               device_reduce=dev).start()
-        assert eng.submit(1, tree)
+        assert eng.submit(1, src)
         eng.close()
-    ref = Catalog(roots["ref"])
-    md_host = Catalog(roots["md_host"])
-    md_dev = Catalog(roots["md_dev"])
+    cats = {name: Catalog(root) for name, root in roots.items()}
+    ref, md_dev = cats["ref"], cats["md_dev"]
     pname, hname = "proj-density-ax2-r32", "hist-density-b16-lo-8-hi8"
     assert md_dev.domains(1, pname) == [0, 1]
-    # device multi-domain == host multi-domain, bit for bit (merged and
-    # per domain)
-    for reducer in (pname, hname):
-        assert_tree_equal(md_host.query(1, reducer),
-                          md_dev.query(1, reducer))
-        for d in (0, 1):
-            assert_tree_equal(md_host.query(1, reducer, domain=d),
-                              md_dev.query(1, reducer, domain=d))
+    # device multi-domain vs host multi-domain under §18 (merged and per
+    # domain): projection within rtol, histogram exact on cast values
+    for r in ReducerDAG(mk()).order:
+        for d in (None, 0, 1):
+            q = {name: cats[name].query(1, r.name, domain=d)
+                 for name in ("md_host", "md_cast", "md_dev")}
+            err, limit = parity_check(r, q["md_dev"], q["md_host"],
+                                      q["md_cast"])
+            assert err <= limit, (r.name, d, err, limit)
     # and the merged answers recover the single-domain reference: the
-    # projection to fp roundoff (sum-merge reorders adds), histogram
+    # projection to float32 roundoff of the single-domain device image
+    # (same float32 tables; the sum-merge only reorders adds — 0.0 on
+    # this tree) and within §18 of the float64 host image, histogram
     # counts exactly (padded rows aside, every leaf counted once)
-    a = ref.query(1, pname)["image"]
     b = md_dev.query(1, pname)["image"]
-    np.testing.assert_allclose(a, b, rtol=1e-12, atol=1e-12)
+    np.testing.assert_allclose(b, ref.query(1, pname)["image"], rtol=1e-6)
+    np.testing.assert_allclose(b, cats["md_host"].query(1, pname)["image"],
+                               rtol=1e-4)
     ha, hb = ref.query(1, hname)["hist"], md_dev.query(1, hname)["hist"]
     assert ha.sum() == hb.sum()
     rows = min(ha.shape[0], hb.shape[0])
     np.testing.assert_array_equal(ha[:rows], hb[:rows])
-    for cat in (ref, md_host, md_dev):
+    for cat in cats.values():
         cat.close()
 
 
@@ -261,3 +291,19 @@ def test_engine_device_reduce_rejects_process_backend(tmp_path):
         InTransitEngine(str(tmp_path / "db"),
                         [SliceReducer(resolution=32)],
                         device_reduce=True, backend="process")
+
+
+@pytest.mark.parametrize("mode", [True, "mesh"])
+def test_insitu_cli_flags_device_reducers_run_on_host(mode, tmp_path):
+    """launch.insitu fails a run in which a reducer with a device impl
+    ran on the host: at a non-pow2 resolution the slice and projection
+    fall back; at a pow2 one only the source-chained slice runs on host
+    and the run passes."""
+    from repro.launch.insitu import main
+    flag = ["--device-reduce"] if mode is True else ["--device-mesh", "1"]
+    for resolution, rc in ((48, 1), (32, 0)):
+        argv = flag + ["--out", str(tmp_path / f"r{resolution}"),
+                       "--steps", "2", "--max-level", "4", "--lod", "2",
+                       "--resolution", str(resolution), "--queries", "1",
+                       "--policy", "block"]
+        assert main(argv) == rc, resolution
