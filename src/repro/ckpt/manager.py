@@ -13,7 +13,9 @@ stages, each its own span:
   ``ckpt.stage``     (gather thread) — tensors cross to the host *one
       at a time* (bounded host memory), are delta- or raw-encoded,
       CRC32-stamped and pushed into the owning contributor group's
-      staging area.
+      staging area; one child span each, in that order: ``ckpt.pull``,
+      ``ckpt.encode``, ``ckpt.crc``, ``ckpt.enqueue`` (which blocks
+      while the group's lane is behind).
   ``ckpt.write``     (writer lanes, thread or process) — append to the
       group's Hercule files and publish to the page cache
       (``flush_domain(sync=False)``); no fsync here.
@@ -218,22 +220,28 @@ class AsyncCheckpointManager:
             domain = int(domain)
             with TRACER.span("ckpt.stage", cat="ckpt", parent=pend.tctx,
                              args={"step": step, "tensor": name}):
-                host = np.asarray(data)   # one tensor on the host at a time
-                entry[4] = None           # release the device copy now
-                codec, payload, meta = self._encode(name, domain, host,
-                                                    full=full)
-                crc = zlib.crc32(payload) & 0xFFFFFFFF
-                desc = {
-                    "rec_name": api.HPROT_SHARD.record_name(name),
-                    "domain": domain, "dtype": str(host.dtype),
-                    "shape": list(host.shape), "codec": codec,
-                    "rec_meta": {**meta, "slices": slices,
-                                 "global_shape": list(gshape),
-                                 "crc32": int(crc)},
-                    "_trace": pend.tctx,
-                }
-                self._backend.push(self.db.group_of(domain), step,
-                                   np.frombuffer(payload, np.uint8), desc)
+                with TRACER.span("ckpt.pull", cat="ckpt"):
+                    host = np.asarray(data)   # one tensor on the host
+                    entry[4] = None           # release the device copy now
+                with TRACER.span("ckpt.encode", cat="ckpt"):
+                    codec, payload, meta = self._encode(name, domain, host,
+                                                        full=full)
+                with TRACER.span("ckpt.crc", cat="ckpt"):
+                    crc = zlib.crc32(payload) & 0xFFFFFFFF
+                # blocks while the owning group's lane is behind
+                with TRACER.span("ckpt.enqueue", cat="ckpt"):
+                    desc = {
+                        "rec_name": api.HPROT_SHARD.record_name(name),
+                        "domain": domain, "dtype": str(host.dtype),
+                        "shape": list(host.shape), "codec": codec,
+                        "rec_meta": {**meta, "slices": slices,
+                                     "global_shape": list(gshape),
+                                     "crc32": int(crc)},
+                        "_trace": pend.tctx,
+                    }
+                    self._backend.push(self.db.group_of(domain), step,
+                                       np.frombuffer(payload, np.uint8),
+                                       desc)
             count += 1
             if obs_metrics.ENABLED:
                 self._c_bytes.labels(codec).inc(len(payload))

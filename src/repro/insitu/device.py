@@ -466,19 +466,25 @@ class DeviceDAGRunner:
                     dt = self._make_view(snap)
                 moved = 0
                 out = {}
-                # spans nest under the lane's open "reduce" span;
-                # np.asarray is where the async device work lands
+                # spans nest under the lane's open "reduce" span: the
+                # dispatch (the view's lazy prep and pads, the kernels'
+                # launch), then the pull — np.asarray is where the async
+                # device work and the device-to-host copy land
                 with TRACER.span("device.transfer",
                                  args={"reducer": r.name}) as sp:
-                    for k, v in impl(dt).items():
-                        if isinstance(v, jax.Array):
-                            moved += v.nbytes
-                            v = np.asarray(v)
-                            if v.dtype.kind == "i":
-                                # the host reducers' integer width, so
-                                # catalogs hold one dtype on every path
-                                v = v.astype(np.int64)
-                        out[k] = v
+                    with TRACER.span("device.dispatch"):
+                        res = impl(dt)
+                    with TRACER.span("device.pull"):
+                        for k, v in res.items():
+                            if isinstance(v, jax.Array):
+                                moved += v.nbytes
+                                v = np.asarray(v)
+                                if v.dtype.kind == "i":
+                                    # the host reducers' integer width,
+                                    # so catalogs hold one dtype on
+                                    # every path
+                                    v = v.astype(np.int64)
+                            out[k] = v
                     sp.set(nbytes=moved)
                 with self._lock:
                     self.stats.device_objects += 1
@@ -505,7 +511,8 @@ class DeviceDAGRunner:
                     host_arrays, moved = {}, 0
                     with TRACER.span("device.transfer",
                                      args={"reducer": r.name,
-                                           "fallback": True}) as sp:
+                                           "fallback": True}) as sp, \
+                            TRACER.span("device.pull"):
                         for k, v in snap.arrays.items():
                             if isinstance(v, jax.Array):
                                 moved += v.nbytes

@@ -153,8 +153,9 @@ class ThreadLaneBackend(LaneBackend):
                     return
                 continue
             tctx = snap.meta.get("_trace")
-            if tctx is not None:
-                # dequeue latency: staged -> picked up by this lane
+            if tctx is not None and t0:
+                # dequeue latency: staged -> picked up by this lane (not
+                # when the tracer came on during the wait: no start)
                 TRACER.record("stage.pop", t0, now_us(), parent=tctx,
                               args={"step": snap.step,
                                     "group": snap.domain})

@@ -37,6 +37,8 @@ import time
 
 import numpy as np
 
+from ..obs.trace import TRACER
+
 POLICIES = ("block", "drop-oldest", "subsample")
 
 
@@ -358,7 +360,8 @@ class StagingArea:
             while len(self._queue) >= self.capacity or not self._free:
                 if self.policy == "block":
                     t0 = time.perf_counter()
-                    self._not_full.wait(timeout=0.5)
+                    with TRACER.span("stage.wait"):
+                        self._not_full.wait(timeout=0.5)
                     self.stats.block_seconds += time.perf_counter() - t0
                     if self._closed:
                         raise RuntimeError("staging area is closed")
@@ -379,7 +382,8 @@ class StagingArea:
         # the (possibly large) staging copy runs without the lock so
         # consumers keep popping/releasing; the buffer set is reserved
         try:
-            host, reuses, allocs, nbytes = bufset.fill(arrays)
+            with TRACER.span("stage.upload"):
+                host, reuses, allocs, nbytes = bufset.fill(arrays)
         except BaseException:
             with self._lock:       # failed copy must not leak the pool
                 self._free.append(bufset)
@@ -409,7 +413,8 @@ class StagingArea:
                             self._reclaim(snap)
                             raise RuntimeError("staging area is closed")
                         t0 = time.perf_counter()
-                        self._not_full.wait(timeout=0.5)
+                        with TRACER.span("stage.wait"):
+                            self._not_full.wait(timeout=0.5)
                         self.stats.block_seconds += \
                             time.perf_counter() - t0
             self._queue.append(snap)
@@ -754,7 +759,8 @@ class ShmStagingArea:
                 if self._words[2] < self.capacity and free.size:
                     break
                 if self.policy == "block":
-                    self._wait_block()
+                    with TRACER.span("stage.wait"):
+                        self._wait_block()
                     if self._words[0]:
                         raise RuntimeError("staging area is closed")
                     continue
@@ -769,8 +775,9 @@ class ShmStagingArea:
             self._state[slot] = _RESERVED
         # the (possibly large) copy into the slab runs without the lock
         try:
-            gen, nbytes, reused = self._fill(slot, step, arrays, kind,
-                                             meta, domain, n_domains)
+            with TRACER.span("stage.upload"):
+                gen, nbytes, reused = self._fill(slot, step, arrays, kind,
+                                                 meta, domain, n_domains)
         except BaseException:
             with self._lock:
                 self._state[slot] = _FREE
@@ -793,7 +800,8 @@ class ShmStagingArea:
                         if self._words[0]:
                             self._state[slot] = _FREE
                             raise RuntimeError("staging area is closed")
-                        self._wait_block()
+                        with TRACER.span("stage.wait"):
+                            self._wait_block()
             self._meta[slot] = (step, gen, domain,
                                 _KIND_CODES.get(kind, 0))
             self._ring[(self._words[1] + self._words[2]) % self.n_slots] \

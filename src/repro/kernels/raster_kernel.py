@@ -211,6 +211,7 @@ def slice_raster(u0, v0, px, lvl, val, ok, *, resolution: int,
             jax.ShapeDtypeStruct((resolution, resolution), val.dtype),
             jax.ShapeDtypeStruct((resolution, resolution), jnp.int32),
         ],
+        name="slice_raster",
         interpret=interpret,
     )(u0, v0, px, lvl, val, ok)
     return img
@@ -244,6 +245,7 @@ def slice_raster_carry(u0, v0, px, lvl, val, ok, img0, depth0, *,
             jax.ShapeDtypeStruct((resolution, resolution), val.dtype),
             jax.ShapeDtypeStruct((resolution, resolution), jnp.int32),
         ],
+        name="slice_raster_carry",
         interpret=interpret,
     )(u0, v0, px, lvl, val, ok, img0, depth0)
     return img, depth
@@ -313,6 +315,7 @@ def projection_raster(u0, v0, px, contrib, ok, *, resolution: int,
         out_specs=out,
         out_shape=jax.ShapeDtypeStruct((resolution, resolution),
                                        contrib.dtype),
+        name="projection_raster",
         interpret=interpret,
     )(u0, v0, px, contrib, ok)
 
@@ -341,6 +344,7 @@ def projection_raster_carry(u0, v0, px, contrib, ok, img0, *,
         out_specs=out,
         out_shape=jax.ShapeDtypeStruct((resolution, resolution),
                                        contrib.dtype),
+        name="projection_raster_carry",
         interpret=interpret,
     )(u0, v0, px, contrib, ok, img0)
 
@@ -401,6 +405,7 @@ def level_hist(val, lvl, ok, edges2, *, n_levels: int, bins: int,
         in_specs=[tbl, tbl, tbl, col, col],
         out_specs=pl.BlockSpec((lp, bp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((lp, bp), jnp.int32),
+        name="level_hist",
         interpret=interpret,
     )(val, lvl, ok, edges2[0][:, None], edges2[1][:, None])
     return hist[:n_levels, :bins]

@@ -28,6 +28,9 @@ STAGE_OF_NAME = {
     "submit": "submit",
     "stage.push": "staging",
     "stage.pop": "staging",
+    "stage.wait": "staging",
+    "stage.upload": "staging",
+    "jit.compile": "compile",
     "reduce": "reduce",
     "write": "write",
     "manifest.commit": "commit",

@@ -17,6 +17,26 @@ chrome://tracing or https://ui.perfetto.dev loads it directly.
 Tracing is OFF by default — ``span()`` returns a shared no-op object
 and costs one attribute read; ``launch/insitu.py --trace-out`` enables
 the global ``TRACER`` for a run.
+
+Two clocks. Span timestamps are microseconds since the unix epoch (the
+Chrome-trace export and the run ledger read them). A JAX profiler trace
+counts from the start of its own session, so a span's ``ts`` cannot be
+laid over the device's ops. :meth:`Tracer.enable` therefore also makes
+every ``with``-opened span enter a ``jax.profiler.TraceAnnotation`` of
+the same name: while a profiler session records, each such span shows on
+the host plane of its trace, on the profiler's clock, on the thread that
+opened it. Spans logged after the fact with :meth:`Tracer.record` — the
+process lanes' ``stage.pop``/``reduce``/``write``/``ckpt.write`` and the
+thread lanes' ``stage.pop`` — and spans :meth:`Tracer.ingest`-ed from
+another process keep only the epoch clock.
+
+``enable()`` also listens to JAX's compile events (``jax.monitoring``):
+each backend compile becomes a ``jit.compile`` span (``args["fun"]``)
+under whatever span the compiling thread has open, so a trace shows
+which step recompiled. ``disable()`` removes the listeners; a disabled
+tracer costs nothing, not even on a compile. A tracer constructed with
+``enabled=True`` (the process lanes' local tracers) records spans but
+hooks neither the profiler nor the compile events.
 """
 from __future__ import annotations
 
@@ -24,11 +44,16 @@ import collections
 import itertools
 import json
 import os
+import random
 import threading
 import time
-import uuid
 
 _EPOCH_NS = time.time_ns() - time.perf_counter_ns()
+
+#: the ``jax.monitoring`` event that brackets one backend compile: a
+#: scalar (its start) on entry, a duration on exit, both on the
+#: compiling thread and both carrying ``fun_name``
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _now_us() -> float:
@@ -37,14 +62,17 @@ def _now_us() -> float:
 
 
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    # a PRNG draw, not uuid4: os.urandom gives up the GIL, and a span
+    # opened on a busy host then waits up to a switch interval to get it
+    # back, outside any span (``random`` reseeds itself in a forked child)
+    return f"{random.getrandbits(64):016x}"
 
 
 class Span:
     """One timed unit of pipeline work (Chrome-trace complete event)."""
 
     __slots__ = ("name", "cat", "trace_id", "span_id", "parent_id",
-                 "ts", "dur", "args", "_tracer")
+                 "ts", "dur", "args", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  trace_id: str, parent_id: str | None, args=None):
@@ -57,11 +85,16 @@ class Span:
         self.dur = 0.0
         self.args = dict(args) if args else {}
         self._tracer = tracer
+        self._annotation = None
 
     def set(self, **kw) -> None:
         self.args.update(kw)
 
     def __enter__(self):
+        annotate = self._tracer._annotate
+        if annotate is not None:
+            self._annotation = annotate(self.name)
+            self._annotation.__enter__()
         self._tracer._push(self)
         return self
 
@@ -69,6 +102,9 @@ class Span:
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
         self._tracer._pop(self)
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         return False
 
     def context(self) -> dict:
@@ -130,13 +166,57 @@ class Tracer:
         self._appended = 0          # lifetime spans, incl. fallen-off
         self._lock = threading.Lock()
         self._tls = threading.local()
+        #: ``jax.profiler.TraceAnnotation`` while enabled by enable()
+        self._annotate = None
+        #: enable() registered the compile listeners
+        self._hooked = False
 
     # --------------------------------------------------------- lifecycle
     def enable(self) -> None:
+        """Start recording; hook the profiler and the compile events."""
         self.enabled = True
+        if self._hooked:
+            return
+        try:
+            import jax.monitoring
+            import jax.profiler
+        except ImportError:      # a stdlib-only process: spans alone
+            return
+        self._annotate = jax.profiler.TraceAnnotation
+        self._hooked = True
+        jax.monitoring.register_scalar_listener(self._on_compile_start)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_compile_end)
 
     def disable(self) -> None:
+        """Stop recording; unhook what enable() hooked."""
         self.enabled = False
+        self._annotate = None
+        if not self._hooked:
+            return
+        import jax.monitoring
+        self._hooked = False
+        jax.monitoring.unregister_scalar_listener(self._on_compile_start)
+        jax.monitoring.unregister_event_duration_listener(
+            self._on_compile_end)
+
+    def _on_compile_start(self, event: str, value, **kw) -> None:
+        if event != COMPILE_EVENT:
+            return
+        sp = self.span("jit.compile", cat="jax",
+                       args={"fun": kw.get("fun_name", "")})
+        sp.__enter__()
+        st = getattr(self._tls, "compiles", None)
+        if st is None:
+            st = self._tls.compiles = []
+        st.append(sp)
+
+    def _on_compile_end(self, event: str, secs: float, **kw) -> None:
+        if event != COMPILE_EVENT:
+            return
+        st = getattr(self._tls, "compiles", None)
+        if st:
+            st.pop().__exit__(None, None, None)
 
     def clear(self) -> None:
         with self._lock:
