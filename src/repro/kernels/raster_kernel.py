@@ -19,16 +19,26 @@ for bit; non-pow2 resolutions take the host fallback in
 Kernel shape: the grid walks leaf blocks *sequentially* while the full
 output image (or histogram) stays resident in VMEM across grid steps
 (constant ``index_map``, initialized on the first step). Inside a block
-the slice/projection kernels ``fori_loop`` over leaves, reading each
-leaf's scalars from ``(1, BLOCK_N)`` tables in SMEM (vector memory
-cannot serve a per-leaf dynamic lane index), and update the image
-through a broadcast rectangle mask — masked ``where`` updates, never
-scatter, so per-pixel update *order* equals the host reducers' BFS
-traversal. At R=512 the carry kernels' planes fit the default scoped
-VMEM, so no ``vmem_limit_bytes`` is set. The histogram kernel is fully
-vectorized: leaves ride the lane axis, a (B+1, BLOCK) edge-compare
-against float64 edges split into float32 pairs (:func:`split_edges`)
-reproduces ``np.searchsorted(edges, v, "right")``, and an MXU
+the slice/projection kernels ``fori_loop`` over the table's rows,
+reading each row's scalars from ``(1, BLOCK_N)`` tables in SMEM (vector
+memory cannot serve a per-leaf dynamic lane index). A row whose ``ok``
+is 0 (interior node, padding, unowned leaf, a leaf the slice plane
+misses) does no vector work. A painting row visits only the image
+tiles its rectangle covers: tiles of ``(min(8, R), min(128, R))``,
+one vreg, from the row's own ``u0``, ``v0``, ``px``
+(:func:`_tile_window`; a leaf of 8 px or less sits in one tile), each
+updated through a rectangle mask built from a tile iota — masked
+``where`` updates, never scatter. Each pixel is still updated by the
+same leaves, in BFS row order, with the same operation as a pass over
+the whole image, so per-pixel update *order* equals the host reducers'
+BFS traversal and the results are bit for bit those of the whole-image
+form. :func:`footprint_tiles` counts the rows that paint and the tiles
+they visit, on the host. At R=512 the carry kernels' planes fit the
+default scoped VMEM, so no ``vmem_limit_bytes`` is set. The histogram
+kernel is fully vectorized: leaves ride the lane axis, a (B+1, BLOCK)
+edge-compare against float64 edges split into float32 pairs
+(:func:`split_edges`) reproduces ``np.searchsorted(edges, v,
+"right")``, and an MXU
 contraction of level and bin one-hots is the blocked scatter-add
 (integer counts — order-free).
 
@@ -136,21 +146,102 @@ def _smem_table(block_n: int):
                         memory_space=pltpu.SMEM)
 
 
+def _tile_shape(resolution: int) -> tuple[int, int]:
+    """One (8, 128) vreg tile of the image, or the whole image if smaller."""
+    return min(8, resolution), min(128, resolution)
+
+
+def _tile_window(u0, v0, px, *, resolution: int, xp=jnp):
+    """First tile origin and tile counts of a leaf's rectangle.
+
+    Works on traced int32 scalars in the kernels (``xp=jnp``) and on
+    numpy arrays in :func:`footprint_tiles` (``xp=np``). The rectangle
+    is clipped to the image first, so a row outside it gets a zero count
+    and touches no memory. For a leaf of the tree ``u0 = c << (k-l)`` is
+    a multiple of ``px``, so a leaf with ``px <= 8`` lies in one tile
+    row and one with ``px <= 128`` in one tile column.
+    """
+    th, tw = _tile_shape(resolution)
+    sh, sw = th.bit_length() - 1, tw.bit_length() - 1
+    u_lo, v_lo = xp.maximum(u0, 0), xp.maximum(v0, 0)
+    u_hi = xp.minimum(u0 + px, resolution)
+    v_hi = xp.minimum(v0 + px, resolution)
+    r_lo, c_lo = (u_lo >> sh) << sh, (v_lo >> sw) << sw
+    # tile sizes are powers of two: ceil-divide by shifting
+    nr = xp.maximum((u_hi - r_lo + th - 1) >> sh, 0)
+    nc = xp.maximum((v_hi - c_lo + tw - 1) >> sw, 0)
+    return r_lo, c_lo, nr, nc
+
+
+def _paint_tiles(u0, v0, px, update, *, resolution: int):
+    """Call ``update(rows, cols, rect)`` on each tile of a leaf's rectangle.
+
+    ``rows``/``cols`` are the tile's ``pl.ds`` slices and ``rect`` its
+    (th, tw) in-rectangle mask. Tiles never overlap, so each pixel is
+    updated once per leaf, as the whole-image pass did.
+    """
+    th, tw = _tile_shape(resolution)
+    r_lo, c_lo, nr, nc = _tile_window(u0, v0, px, resolution=resolution)
+    ti = jax.lax.broadcasted_iota(jnp.int32, (th, tw), 0)
+    tj = jax.lax.broadcasted_iota(jnp.int32, (th, tw), 1)
+
+    def at(lo, n, size):
+        # a tile as tall or wide as the image sits at 0: Mosaic wants a
+        # static offset where the slice is narrower than 128 lanes
+        return 0 if size == resolution else pl.multiple_of(lo + n * size,
+                                                           size)
+
+    def row(a, _):
+        r = at(r_lo, a, th)
+
+        def col(b, _):
+            c = at(c_lo, b, tw)
+            rows, cols = ti + r, tj + c
+            rect = ((rows >= u0) & (rows < u0 + px)
+                    & (cols >= v0) & (cols < v0 + px))
+            update(pl.ds(r, th), pl.ds(c, tw), rect)
+            return 0
+
+        return jax.lax.fori_loop(0, nc, col, 0)
+
+    jax.lax.fori_loop(0, nr, row, 0)
+
+
+def footprint_tiles(u0, v0, px, ok, resolution: int) -> tuple[int, int]:
+    """Rows that paint and tiles they paint, for a host-side leaf table.
+
+    The raster kernels' own window arithmetic in numpy: a table's
+    ``(rows_painting, tiles_painted)`` is how much of it the ``ok`` skip
+    leaves and how many (8, 128) tile updates the painting rows make.
+    ``ok`` is what the kernel reads (for the slice, leaf ∧ owner ∧
+    plane hit).
+    """
+    sel = np.asarray(ok).reshape(-1) != 0
+    u0, v0, px = (np.asarray(a, np.int64).reshape(-1)[sel]
+                  for a in (u0, v0, px))
+    _, _, nr, nc = _tile_window(u0, v0, px, resolution=resolution, xp=np)
+    return int(sel.sum()), int((nr * nc).sum())
+
+
 def _slice_body(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
                 img_ref, depth_ref, *, block_n: int, resolution: int):
-    rows = jax.lax.broadcasted_iota(jnp.int32, (resolution, resolution), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (resolution, resolution), 1)
-
     def body(i, _):
-        u0, v0, px = u0_ref[0, i], v0_ref[0, i], px_ref[0, i]
-        lvl, val, ok = lvl_ref[0, i], val_ref[0, i], ok_ref[0, i]
-        rect = ((rows >= u0) & (rows < u0 + px)
-                & (cols >= v0) & (cols < v0 + px))
-        # deepest leaf wins; equal level repaints (leaves arrive in BFS
-        # order, so this is exactly the host painter's later-overrides)
-        mask = rect & (ok != 0) & (lvl >= depth_ref[...])
-        img_ref[...] = jnp.where(mask, val, img_ref[...])
-        depth_ref[...] = jnp.where(mask, lvl, depth_ref[...])
+        @pl.when(ok_ref[0, i] != 0)
+        def _paint():
+            lvl, val = lvl_ref[0, i], val_ref[0, i]
+
+            def update(rows, cols, rect):
+                # deepest leaf wins; equal level repaints (leaves arrive
+                # in BFS order, so this is exactly the host painter's
+                # later-overrides)
+                depth = depth_ref[rows, cols]
+                mask = rect & (lvl >= depth)
+                img_ref[rows, cols] = jnp.where(mask, val,
+                                                img_ref[rows, cols])
+                depth_ref[rows, cols] = jnp.where(mask, lvl, depth)
+
+            _paint_tiles(u0_ref[0, i], v0_ref[0, i], px_ref[0, i], update,
+                         resolution=resolution)
         return 0
 
     jax.lax.fori_loop(0, block_n, body, 0)
@@ -255,18 +346,21 @@ def slice_raster_carry(u0, v0, px, lvl, val, ok, img0, depth0, *,
 
 def _proj_body(u0_ref, v0_ref, px_ref, contrib_ref, ok_ref, img_ref, *,
                block_n: int, resolution: int):
-    rows = jax.lax.broadcasted_iota(jnp.int32, (resolution, resolution), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (resolution, resolution), 1)
-
     def body(i, _):
-        u0, v0, px = u0_ref[0, i], v0_ref[0, i], px_ref[0, i]
-        contrib, ok = contrib_ref[0, i], ok_ref[0, i]
-        mask = ((rows >= u0) & (rows < u0 + px)
-                & (cols >= v0) & (cols < v0 + px) & (ok != 0))
-        # where-guarded add: pixels outside the rectangle are untouched
-        # (no +0.0), and per-pixel adds run in BFS leaf order — the same
-        # float accumulation sequence as the host reducer
-        img_ref[...] = jnp.where(mask, img_ref[...] + contrib, img_ref[...])
+        @pl.when(ok_ref[0, i] != 0)
+        def _paint():
+            contrib = contrib_ref[0, i]
+
+            def update(rows, cols, rect):
+                # where-guarded add: pixels outside the rectangle are
+                # untouched (no +0.0), and per-pixel adds run in BFS leaf
+                # order — the same float accumulation sequence as the
+                # host reducer
+                img = img_ref[rows, cols]
+                img_ref[rows, cols] = jnp.where(rect, img + contrib, img)
+
+            _paint_tiles(u0_ref[0, i], v0_ref[0, i], px_ref[0, i], update,
+                         resolution=resolution)
         return 0
 
     jax.lax.fori_loop(0, block_n, body, 0)

@@ -3,10 +3,11 @@
 Interpret mode (the CPU tests) accepts kernels the TPU compiler refuses:
 unaligned vector loads, in-kernel gathers, more VMEM than a kernel may
 use. These tests compile each kernel at its production size (R=512,
-N=4096 leaves, float32 tables) for one chip of a described ``v5e:2x2``
-topology — no chip attached — and check the Mosaic kernel is in the
-compiled program. The topology is described inside a fixture, so only
-the worker that runs this file loads the TPU compiler.
+N=4096 leaves, float32 tables), and the slice and projection at R=32
+(an image tile narrower than 128 lanes), for one chip of a described
+``v5e:2x2`` topology — no chip attached — and check the Mosaic kernel
+is in the compiled program. The topology is described inside a fixture,
+so only the worker that runs this file loads the TPU compiler.
 """
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,13 @@ KERNELS = {
     "projection_raster_carry": (
         lambda *a: rk.projection_raster_carry(*a, resolution=R),
         [TABLE_I] * 3 + [TABLE_F, TABLE_I, IMAGE]),
+    # R=32: the tile is (8, 32), narrower than the 128 lanes
+    "slice_raster_r32": (
+        lambda *a: rk.slice_raster(*a, resolution=32),
+        [TABLE_I] * 4 + [TABLE_F, TABLE_I]),
+    "projection_raster_r32": (
+        lambda *a: rk.projection_raster(*a, resolution=32),
+        [TABLE_I] * 3 + [TABLE_F, TABLE_I]),
     "level_hist": (
         lambda *a: rk.level_hist(*a, n_levels=LEVELS, bins=BINS),
         [TABLE_F, TABLE_I, TABLE_I, ((2, BINS + 1), jnp.float32)]),
