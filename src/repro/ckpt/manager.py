@@ -5,13 +5,17 @@ machinery (DESIGN.md §16). One save decomposes into four pipeline
 stages, each its own span:
 
   ``ckpt.snapshot``  (train thread, *the only stall*) — a
-      snapshot-consistent cut of the state: every owned shard is copied
-      device-side (``jnp.array``; donation-safe — the optimizer may
-      overwrite the source buffers the moment save returns) and the
-      copies fenced with one ``block_until_ready``. No host transfer,
-      no serialization.
-  ``ckpt.stage``     (gather thread) — tensors cross to the host *one
-      at a time* (bounded host memory), are delta- or raw-encoded,
+      snapshot-consistent cut of the state, donation-safe: the optimizer
+      may overwrite the source buffers the moment save returns. Owned
+      shards are copied device-side (``jnp.array``, child span
+      ``ckpt.cut.device``) while their bytes fit the HBM the steps leave
+      free (``_cut_budget``); the rest cross to the host inside the
+      stall, every transfer started before any is waited on (child span
+      ``ckpt.cut.host``, counter ``ckpt_cut_host_bytes``). Where the
+      device reports no memory figures (CPU) every shard is copied on
+      the device. No serialization.
+  ``ckpt.stage``     (gather thread) — device copies cross to the host
+      *one at a time* (bounded host memory), are delta- or raw-encoded,
       CRC32-stamped and pushed into the owning contributor group's
       staging area; one child span each, in that order: ``ckpt.pull``,
       ``ckpt.encode``, ``ckpt.crc``, ``ckpt.enqueue`` (which blocks
@@ -75,6 +79,14 @@ class _PendingSave:
     committing: bool = False          # commit claimed by some thread
 
 
+def _host_copy(x: jax.Array) -> np.ndarray:
+    """``x`` on the host, in memory that outlives ``x``'s buffers: an
+    accelerator's transfer lands in a fresh host buffer, while the CPU
+    backend may hand out a view of the device buffer itself."""
+    host = np.asarray(x)
+    return host.copy() if x.devices().pop().platform == "cpu" else host
+
+
 class AsyncCheckpointManager:
     """Async sharded HProt checkpoints over staged writer lanes.
 
@@ -87,7 +99,11 @@ class AsyncCheckpointManager:
     def __init__(self, root: str, *, ncf: int = 8,
                  max_file_bytes: int = 2 << 30, delta_every: int = 0,
                  lane_backend: str = "thread", queue_capacity: int = 4,
-                 io_threads: int = 4, registry=None):
+                 io_threads: int = 4, registry=None,
+                 cut_budget_bytes: int | None = None):
+        """``cut_budget_bytes`` forces the snapshot's device-copy budget
+        (tests only); by default it comes from the device's memory
+        figures (``_cut_budget``)."""
         self.db = HerculeDB.create(root, kind="hprot", ncf=ncf,
                                    max_file_bytes=max_file_bytes,
                                    io_threads=io_threads)
@@ -106,6 +122,11 @@ class AsyncCheckpointManager:
         self._committed = 0
         self._stall_total = 0.0
         self._closed = False
+        self._forced_budget = cut_budget_bytes
+        # per device: the steps' own HBM peak, seen before any cut copy
+        # existed, and the most cut bytes ever held on it at once
+        self._step_peak: dict = {}
+        self._cut_device_max: dict = {}
 
         self.obs = registry if registry is not None \
             else obs_metrics.MetricsRegistry()
@@ -125,6 +146,10 @@ class AsyncCheckpointManager:
             "ckpt_records_total", "checkpoint shard records staged")
         self._c_saves = self.obs.counter(
             "ckpt_saves_total", "checkpoints gathered", labels=("mode",))
+        self._c_cut_host = self.obs.counter(
+            "ckpt_cut_host_bytes", "snapshot bytes cut to the host in the "
+            "stall (the device had no room for their copies)")
+        self._cut_host_bytes = 0
 
         self._backend = make_backend(lane_backend, self,
                                      queue_capacity=queue_capacity)
@@ -161,9 +186,42 @@ class AsyncCheckpointManager:
         if wait:
             self.wait()
 
+    #: HBM kept free beside the steps' peak and the cut's device copies
+    CUT_MARGIN = 512 << 20
+
+    def _cut_budget(self, device) -> int | None:
+        """Bytes of device copies the cut may make on ``device``: its
+        ``bytes_limit`` less the steps' peak less :attr:`CUT_MARGIN`;
+        ``None`` (no limit) where the device reports no memory figures.
+
+        The steps' peak is the peak of live buffers plus the peak of the
+        memory programs reserve for their temporaries while they run (a
+        TPU keeps the two apart: ``peak_bytes_in_use`` and
+        ``peak_bytes_reserved``), read at the first save, after the job's
+        steps ran. ``peak_bytes_in_use`` also counts earlier cuts' copies,
+        so a later reading less the most copy bytes ever held on that
+        device raises the peak, and the budget does not shrink save after
+        save by the cut's own copies. Each device keeps its own figures."""
+        if self._forced_budget is not None:
+            return self._forced_budget
+        stats = device.memory_stats() if hasattr(device, "memory_stats") \
+            else None
+        if not stats or "bytes_limit" not in stats:
+            return None
+        used = int(stats.get("peak_bytes_in_use", stats.get("bytes_in_use", 0)))
+        reserved = int(stats.get("peak_bytes_reserved",
+                                 stats.get("bytes_reserved", 0)))
+        peak = used - self._cut_device_max.get(device, 0) + reserved
+        peak = max(self._step_peak.get(device, peak), peak)
+        self._step_peak[device] = peak
+        return max(0, int(stats["bytes_limit"]) - peak - self.CUT_MARGIN)
+
     def _snapshot(self, state) -> list:
-        """Donation-safe consistent cut: device copies, no host traffic."""
-        cut, fences = [], []
+        """Donation-safe consistent cut: device copies within the budget,
+        the rest pulled to the host before ``save`` returns."""
+        cut, on_device, to_host = [], [], []
+        budgets: dict = {}
+        used: dict = {}
         for name, leaf in _leaf_paths(state):
             if leaf is None:
                 continue
@@ -177,16 +235,45 @@ class AsyncCheckpointManager:
                     if key in seen:
                         continue   # ghost replica — ownership pruning
                     seen.add(key)
-                    data = jnp.array(sh.data)   # guaranteed device copy
-                    fences.append(data)
-                    cut.append([name, sh.device.id,
-                                _slices_json(sh.index, gshape), gshape,
-                                data])
+                    dev = sh.device
+                    if dev not in budgets:
+                        budgets[dev] = self._cut_budget(dev)
+                        used[dev] = 0
+                    entry = [name, dev.id, _slices_json(sh.index, gshape),
+                             gshape, sh.data]
+                    nbytes = sh.data.nbytes
+                    if budgets[dev] is None or \
+                            used[dev] + nbytes <= budgets[dev]:
+                        used[dev] += nbytes
+                        on_device.append(entry)
+                    else:
+                        to_host.append(entry)
+                    cut.append(entry)
             else:
                 data = np.array(leaf, copy=True)
                 cut.append([name, 0, [], tuple(data.shape), data])
-        if fences:
-            jax.block_until_ready(fences)
+        if on_device:
+            with TRACER.span("ckpt.cut.device", cat="ckpt",
+                             args={"tensors": len(on_device)}):
+                for entry in on_device:
+                    entry[4] = jnp.array(entry[4])  # guaranteed device copy
+                jax.block_until_ready([e[4] for e in on_device])
+        if to_host:
+            nbytes = sum(e[4].nbytes for e in to_host)
+            with TRACER.span("ckpt.cut.host", cat="ckpt",
+                             args={"tensors": len(to_host),
+                                   "bytes": nbytes}):
+                for entry in to_host:       # start every transfer first
+                    entry[4].copy_to_host_async()
+                for entry in to_host:
+                    entry[4] = _host_copy(entry[4])
+            with self._lock:
+                self._cut_host_bytes += nbytes
+            if obs_metrics.ENABLED:
+                self._c_cut_host.inc(nbytes)
+        for dev, nbytes in used.items():
+            self._cut_device_max[dev] = max(
+                self._cut_device_max.get(dev, 0), nbytes)
         return cut
 
     # ------------------------------------------------------------- gather
@@ -220,9 +307,12 @@ class AsyncCheckpointManager:
             domain = int(domain)
             with TRACER.span("ckpt.stage", cat="ckpt", parent=pend.tctx,
                              args={"step": step, "tensor": name}):
-                with TRACER.span("ckpt.pull", cat="ckpt"):
-                    host = np.asarray(data)   # one tensor on the host
-                    entry[4] = None           # release the device copy now
+                if isinstance(data, np.ndarray):
+                    host = data               # cut to the host in the stall
+                else:
+                    with TRACER.span("ckpt.pull", cat="ckpt"):
+                        host = np.asarray(data)   # one tensor on the host
+                entry[4] = None               # release the device copy now
                 with TRACER.span("ckpt.encode", cat="ckpt"):
                     codec, payload, meta = self._encode(name, domain, host,
                                                         full=full)
@@ -484,6 +574,7 @@ class AsyncCheckpointManager:
                     "pending": len(self._order),
                     "errors": len(self._errors),
                     "stall_seconds_total": self._stall_total,
+                    "cut_host_bytes": self._cut_host_bytes,
                     "delta_every": self.delta_every,
                     "deltas_since_full": self._deltas_since_full,
                     "backend": self._backend.telemetry()}

@@ -17,6 +17,8 @@ from .layers import ParamSpec
 
 
 def attn_spec(cfg, cross: bool = False) -> dict:
+    if cfg.kv_lora_rank:
+        return mla_spec(cfg)
     d, hd, nh, nkv = cfg.d_model, cfg.hd, cfg.n_heads, cfg.n_kv_heads
     return {
         "wq": ParamSpec((d, nh, hd), ("fsdp", "heads", "head_dim")),
@@ -40,9 +42,10 @@ def _mask_bias(q_pos, k_pos, window):
     return jnp.where(ok, 0.0, -1e30).astype(jnp.float32)
 
 
-def _sdpa(q, k, v, bias):
-    """q: (B,Sq,H,hd) k/v: (B,Sk,H,hd); bias: (Sq,Sk) or None."""
-    scale = q.shape[-1] ** -0.5
+def _sdpa(q, k, v, bias, scale=None):
+    """q/k: (B,Sq,H,hd) and (B,Sk,H,hd), v: (B,Sk,H,hv); bias: (Sq,Sk) or
+    None; ``scale`` defaults to hd**-0.5."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if bias is not None:
@@ -60,7 +63,10 @@ def multihead(p, x, *, cfg, positions, kv_x=None, kv_positions=None,
     With ``return_kv`` also returns the (pre-GQA-repeat, post-RoPE)
     (B, S, nkv, hd) K/V for cache seeding at prefill.
     """
-    b, s, _ = x.shape
+    if cfg.kv_lora_rank:
+        if not causal or kv_x is not None or return_kv:
+            raise NotImplementedError("MLA runs causal self-attention only")
+        return mla(p, x, cfg=cfg, positions=positions)
     dt = x.dtype
     wq = layers.wcast(p["wq"], dt, "fsdp", "heads", "head_dim")
     wk = layers.wcast(p["wk"], dt, "fsdp", "kv_heads", "head_dim")
@@ -83,34 +89,101 @@ def multihead(p, x, *, cfg, positions, kv_x=None, kv_positions=None,
     k = _repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
     v = _repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
 
-    sk = k.shape[1]
     if not causal:
         out = _sdpa(q, k, v, None)
-    elif s <= cfg.attn_chunk:
-        bias = _mask_bias(positions[0] if positions.ndim > 1 else positions,
-                          kpos[0] if kpos.ndim > 1 else kpos, cfg.window)
-        out = _sdpa(q, k, v, bias)
     else:
-        # flash-style: scan over query blocks, full KV per block
-        nblk = s // cfg.attn_chunk
-        assert s % cfg.attn_chunk == 0, (s, cfg.attn_chunk)
-        qb = q.reshape(b, nblk, cfg.attn_chunk, *q.shape[2:])
-        pos1 = positions[0] if positions.ndim > 1 else positions
-        pb = pos1.reshape(nblk, cfg.attn_chunk)
-        kpos1 = kpos[0] if kpos.ndim > 1 else kpos
-
-        def step(_, inp):
-            qi, pi = inp
-            bias = _mask_bias(pi, kpos1, cfg.window)
-            return None, _sdpa(qi, k, v, bias)
-        _, ob = jax.lax.scan(step, None, (jnp.moveaxis(qb, 1, 0), pb))
-        out = jnp.moveaxis(ob, 0, 1).reshape(b, s, *q.shape[2:])
-
+        out = _causal(q, k, v, positions, kpos, cfg)
     out = sharding.constrain(out, "batch", "seq", "heads", "head_dim")
     wo = layers.wcast(p["wo"], dt, "heads", "head_dim", "fsdp")
     # bf16 output so the TP all-reduce moves half the bytes (§Perf i6)
     out = jnp.einsum("bshk,hkd->bsd", out, wo)
     return (out, kv_raw) if return_kv else out
+
+
+def _causal(q, k, v, positions, kpos, cfg, scale=None, remat=False):
+    """Causal (optionally windowed) attention; flash-style scan over
+    ``cfg.attn_chunk`` query blocks, full KV per block, past one block.
+    ``remat`` recomputes each block's scores in the backward pass instead
+    of keeping every block's (B, H, chunk, S) scores and probabilities."""
+    b, s = q.shape[:2]
+    pos1 = positions[0] if positions.ndim > 1 else positions
+    kpos1 = kpos[0] if kpos.ndim > 1 else kpos
+    if s <= cfg.attn_chunk:
+        return _sdpa(q, k, v, _mask_bias(pos1, kpos1, cfg.window), scale)
+    nblk = s // cfg.attn_chunk
+    assert s % cfg.attn_chunk == 0, (s, cfg.attn_chunk)
+    qb = q.reshape(b, nblk, cfg.attn_chunk, *q.shape[2:])
+    pb = pos1.reshape(nblk, cfg.attn_chunk)
+
+    def step(_, inp):
+        qi, pi = inp
+        bias = _mask_bias(pi, kpos1, cfg.window)
+        return None, _sdpa(qi, k, v, bias, scale)
+    if remat:
+        step = jax.checkpoint(step)
+    _, ob = jax.lax.scan(step, None, (jnp.moveaxis(qb, 1, 0), pb))
+    return jnp.moveaxis(ob, 0, 1).reshape(b, s, *ob.shape[3:])
+
+
+# ------------------------------------------------ multi-head latent (MLA)
+
+def mla_spec(cfg) -> dict:
+    """DeepSeek-V2 attention without a query LoRA: per-head queries of
+    ``nope + rope`` channels; one shared KV latent (RMS-normed) of
+    ``kv_lora_rank``, up-projected to per-head ``k_nope`` and ``v``; one
+    rotated key of ``rope`` channels shared by every head."""
+    d, nh, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return {
+        "wq": ParamSpec((d, nh, qk), ("fsdp", "heads", "head_dim")),
+        "wkv_a": ParamSpec((d, r + cfg.qk_rope_head_dim), ("fsdp", None)),
+        "kv_norm": {"scale": ParamSpec((r,), (None,), "zeros")},
+        "wkv_b": ParamSpec((r, nh, cfg.qk_nope_head_dim + cfg.v_head_dim),
+                           (None, "heads", "head_dim")),
+        "wo": ParamSpec((nh, cfg.v_head_dim, d), ("heads", "head_dim", "fsdp")),
+    }
+
+
+def mla_softmax_scale(cfg) -> float:
+    """``(nope + rope)**-0.5``, times YaRN's ``mscale_all_dim`` gain
+    squared when YaRN is on."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor:
+        m = layers.yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def mla(p, x, *, cfg, positions):
+    """Causal MLA over a sequence. x: (B, S, D) -> (B, S, D). The rotated
+    channels use the half-split rotation (the published interleaved one
+    up to a fixed permutation of those weight columns)."""
+    dt = x.dtype
+    nope, rd, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    f32 = jnp.float32
+    if cfg.yarn_factor:
+        inv_freq, cs = layers.yarn_inv_freq(cfg), layers.yarn_cos_scale(cfg)
+    else:
+        inv_freq, cs = None, 1.0
+    with jax.named_scope("mla"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt),
+                       preferred_element_type=f32).astype(dt)
+        ckv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(dt),
+                         preferred_element_type=f32).astype(dt)
+        latent = layers.rmsnorm(ckv[..., :r], p["kv_norm"]["scale"])
+        kv = jnp.einsum("bsr,rhk->bshk", latent, p["wkv_b"].astype(dt),
+                        preferred_element_type=f32).astype(dt)
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        q_pe = layers.rope(q[..., nope:], positions, cfg.rope_theta,
+                           inv_freq, cs)
+        k_pe = layers.rope(ckv[..., None, r:], positions, cfg.rope_theta,
+                           inv_freq, cs)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (*k_nope.shape[:3], rd))], -1)
+        out = _causal(q, k, v, positions, positions, cfg,
+                      mla_softmax_scale(cfg), remat=cfg.remat == "full")
+        return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
 
 
 # ------------------------------------------------------------------ decode

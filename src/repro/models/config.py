@@ -28,6 +28,31 @@ class ModelConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     moe_groups: int = 1          # dispatch groups (ride the data axis)
+    moe_dispatch: str = "capacity"   # capacity (buckets) | dropless (grouped)
+    moe_d_ff: int | None = None  # routed expert width (default d_ff)
+    n_shared_experts: int = 0    # always-on experts of width moe_d_ff each
+    first_k_dense: int = 0       # leading dense layers before the MoE ones
+    norm_topk_prob: bool = True  # renormalise the top-k router weights
+    routed_scaling: float = 1.0
+    # expert parallelism: this chip holds experts [offset, offset + held)
+    expert_offset: int = 0
+    n_experts_held: int | None = None
+    aux_loss: str = "switch"     # switch (global) | seq (per sequence)
+    aux_loss_alpha: float = 0.01
+
+    # multi-head latent attention (MLA); kv_lora_rank 0 = ordinary heads
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # YaRN rotary scaling; factor 0 = plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (mamba2 SSD)
     ssm_state: int = 0
@@ -75,11 +100,20 @@ class ModelConfig:
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
 
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
     def layer_kinds(self) -> list[str]:
         """Per-layer kind sequence for hybrid models."""
         if not self.block_pattern:
             kind = {"ssm": "ssm", "moe": "moe"}.get(self.family, "attn")
-            return [kind] * self.n_layers
+            k = self.first_k_dense
+            return ["attn"] * k + [kind] * (self.n_layers - k)
         pat = list(self.block_pattern)
         return [pat[i % len(pat)] for i in range(self.n_layers)]
 

@@ -11,6 +11,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import sharding
 
@@ -93,9 +94,9 @@ def apply_norm(p, x, cfg):
 
 # ------------------------------------------------------------------- MLPs
 
-def mlp_spec(cfg, d_in=None) -> dict:
+def mlp_spec(cfg, d_in=None, d_ff=None) -> dict:
     d = d_in or cfg.d_model
-    f = cfg.d_ff
+    f = d_ff or cfg.d_ff
     gated = cfg.mlp_act in ("swiglu", "geglu")
     spec = {"wi": ParamSpec((d, f), ("fsdp", "mlp")),
             "wo": ParamSpec((f, d), ("mlp", "fsdp"))}
@@ -150,14 +151,53 @@ def unembed(p, x, cfg):
 
 # ------------------------------------------------------------------- RoPE
 
-def rope(x, positions, theta: float):
-    """x: (..., S, H, hd); positions: (..., S) int."""
+def rope(x, positions, theta: float, inv_freq=None, mscale: float = 1.0):
+    """x: (..., S, H, hd); positions: (..., S) int. Rotates the halves
+    ``(i, i + hd/2)`` by ``position * inv_freq[i]`` (default
+    ``theta**(-2i/hd)``); ``mscale`` scales cos and sin (YaRN)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
-    cos = jnp.cos(angles)[..., None, :]
-    sin = jnp.sin(angles)[..., None, :]
+    cos = jnp.cos(angles)[..., None, :] * mscale
+    sin = jnp.sin(angles)[..., None, :] * mscale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+# ------------------------------------------------------------------- YaRN
+# DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` (arXiv:2309.00071)
+
+def yarn_get_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(cfg) -> np.ndarray:
+    """Per-pair inverse frequencies of the rotated channels: the original
+    ``theta**(-2i/dim)`` for fast pairs, divided by the factor for slow
+    ones, blended by a linear ramp between the correction dims."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    pos = np.arange(0, dim, 2, dtype=np.float32) / dim
+    extra = 1.0 / base ** pos
+    inter = 1.0 / (cfg.yarn_factor * base ** pos)
+
+    def corr_dim(rotations):
+        return (dim * math.log(cfg.yarn_original_max_pos
+                                / (rotations * 2 * math.pi))) \
+            / (2 * math.log(base))
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    mask = 1.0 - ramp
+    return (inter * (1 - mask) + extra * mask).astype(np.float32)
+
+
+def yarn_cos_scale(cfg) -> float:
+    """YaRN's scale of cos and sin: 1 when ``mscale == mscale_all_dim``."""
+    return yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale) \
+        / yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)

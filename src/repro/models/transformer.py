@@ -105,8 +105,12 @@ class LM:
             spec["blocks"] = _stack_specs(self._block_spec("xattn"), cfg.n_layers)
             spec["enc_norm"] = layers.norm_spec(cfg)
         else:
-            kind = self.kinds[0]
-            spec["blocks"] = _stack_specs(self._block_spec(kind), cfg.n_layers)
+            kind = self.kinds[-1]
+            k = cfg.first_k_dense
+            if k:
+                spec["dense"] = _stack_specs(self._block_spec("attn"), k)
+            spec["blocks"] = _stack_specs(self._block_spec(kind),
+                                          cfg.n_layers - k)
         return spec
 
     def param_axes(self):
@@ -122,9 +126,12 @@ class LM:
 
     # ------------------------------------------------------------ blocks
     def _apply_block(self, kind: str, p, x, positions, *, enc_out=None,
-                     enc_pos=None, window_override=None):
+                     enc_pos=None, window_override=None, routes=False):
+        """One block: ``(x, aux loss, stats)``; ``stats`` holds an MoE
+        layer's counters, else is empty."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
+        stats = {}
         if kind in ("attn", "moe", "xattn"):
             h = layers.apply_norm(p["ln1"], x, cfg)
             win = window_override if window_override is not None else cfg.window
@@ -137,7 +144,7 @@ class LM:
                     kv_x=enc_out, kv_positions=enc_pos, causal=False)
             h = layers.apply_norm(p["ln2"], x, cfg)
             if kind == "moe":
-                y, aux = moe.moe_mlp(p["moe"], h, cfg)
+                y, aux, stats = moe.moe_layer(p["moe"], h, cfg, routes=routes)
                 x = x + y
             else:
                 x = x + layers.mlp(p["mlp"], h, cfg)
@@ -154,7 +161,7 @@ class LM:
         else:
             raise ValueError(kind)
         x = sharding.constrain(x, "batch", "seq", "embed")
-        return x, aux
+        return x, aux, stats
 
     @functools.lru_cache(maxsize=8)
     def _cfg_with_window(self, win):
@@ -165,11 +172,16 @@ class LM:
 
     # ----------------------------------------------------------- forward
     def forward(self, params, tokens, *, extras=None, return_cache=False):
-        """Full-sequence forward -> logits (B, S, V) [+ caches].
+        """Full-sequence forward -> ``(logits (B, S, V), aux)``.
 
         ``extras``: {"patch_embeds": (B,P,D)} for vlm, {"frames": (B,F,D)}
         for encdec.
         """
+        return self.forward_stats(params, tokens, extras=extras)[:2]
+
+    def forward_stats(self, params, tokens, *, extras=None, routes=False):
+        """``forward`` plus ``stats``: each MoE layer's counters stacked
+        on a leading layer axis (with ``routes``, its top-k expert ids)."""
         cfg = self.cfg
         extras = extras or {}
         b, s = tokens.shape
@@ -184,24 +196,31 @@ class LM:
         if cfg.family == "encdec":
             enc_out, enc_pos = self._encode(params, extras["frames"])
 
+        stats = {}
         if cfg.block_pattern:
             x, aux_total = self._hybrid_forward(params, x, positions)
         else:
-            kind = "xattn" if cfg.family == "encdec" else self.kinds[0]
-
-            def body(carry, lp):
-                h, aux = carry
-                h, a = self._apply_block(kind, lp, h, positions,
-                                         enc_out=enc_out, enc_pos=enc_pos)
-                return (h, aux + a), None
-            if cfg.remat == "full":
-                body = jax.checkpoint(body)
-            (x, aux_total), _ = maybe_scan(body, (x, aux_total),
-                                           params["blocks"],
-                                           unroll=cfg.unroll_layers)
+            kind = "xattn" if cfg.family == "encdec" else self.kinds[-1]
+            stacks = [("blocks", kind)]
+            if cfg.first_k_dense:
+                stacks.insert(0, ("dense", "attn"))
+            for name, k in stacks:
+                def body(carry, lp, k=k):
+                    h, aux = carry
+                    h, a, st = self._apply_block(k, lp, h, positions,
+                                                 enc_out=enc_out,
+                                                 enc_pos=enc_pos,
+                                                 routes=routes)
+                    return (h, aux + a), st
+                if cfg.remat == "full":
+                    body = jax.checkpoint(body)
+                (x, aux_total), st = maybe_scan(body, (x, aux_total),
+                                                params[name],
+                                                unroll=cfg.unroll_layers)
+                stats.update(st or {})
         x = layers.apply_norm(params["final_norm"], x, cfg)
         logits = layers.unembed(params["embed"], x, cfg)
-        return (logits, aux_total)
+        return (logits, aux_total, stats)
 
     def _encode(self, params, frames):
         cfg = self.cfg
@@ -231,8 +250,8 @@ class LM:
             h, a = carry
             for i, k in enumerate(pat):
                 win = cfg.window if k == "attn" else None
-                h, ai = self._apply_block(k, lp[f"sub{i}_{k}"], h, positions,
-                                          window_override=win)
+                h, ai, _ = self._apply_block(k, lp[f"sub{i}_{k}"], h,
+                                             positions, window_override=win)
                 a = a + ai
             return (h, a), None
         if cfg.remat == "full":
@@ -241,20 +260,33 @@ class LM:
                                  unroll=cfg.unroll_layers)
         for i, k in enumerate(self.tail_kinds):
             win = cfg.window if k == "attn" else None
-            x, ai = self._apply_block(k, params[f"tail{i}"], x, positions,
-                                      window_override=win)
+            x, ai, _ = self._apply_block(k, params[f"tail{i}"], x,
+                                         positions, window_override=win)
             aux = aux + ai
         return x, aux
 
     # ------------------------------------------------- loss (next token)
     def loss_fn(self, params, batch):
-        logits, aux = self.forward(params, batch["tokens"],
-                                   extras={k: v for k, v in batch.items()
-                                           if k in ("patch_embeds", "frames")})
+        """Mean next-token NLL plus ``aux_loss_alpha`` times the layers'
+        summed balance loss; metrics add the MoE counters over layers."""
+        logits, aux, stats = self.forward_stats(
+            params, batch["tokens"],
+            extras={k: v for k, v in batch.items()
+                    if k in ("patch_embeds", "frames")})
         labels = batch["labels"]
         logits = logits.astype(jnp.float32)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
         mask = (labels >= 0).astype(jnp.float32)
         nll = jnp.sum((lse - ll) * mask) / jnp.clip(mask.sum(), 1.0)
-        return nll + 0.01 * aux, {"loss": nll, "aux": aux}
+        metrics = {"loss": nll, "aux": aux}
+        if stats:
+            metrics.update(
+                moe_assignments_held=stats["moe_assignments_held"].sum(),
+                moe_load_max_over_mean=stats["moe_load_max_over_mean"].max(),
+                moe_dropped=stats["moe_dropped"].sum())
+        return nll + self.cfg.aux_loss_alpha * aux, metrics
+
+    def routes(self, params, tokens):
+        """Each MoE layer's top-k expert ids, (L_moe, B, S, k)."""
+        return self.forward_stats(params, tokens, routes=True)[2]["routes"]

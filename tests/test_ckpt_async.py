@@ -278,3 +278,119 @@ def test_stall_and_metrics_accounting(tmp_path):
     finally:
         TRACER.disable()
         TRACER.clear()
+
+
+# ------------------------------------------------- the budgeted snapshot cut
+
+def _cut_spans(fn):
+    from repro.obs.trace import TRACER
+    TRACER.enable()
+    TRACER.clear()
+    try:
+        fn()
+    finally:
+        TRACER.disable()
+    return [s["name"] for s in TRACER.spans()]
+
+
+@pytest.mark.parametrize("budget", [0, 96 * 32 * 4 + 32 * 4])
+def test_forced_budget_splits_cut_and_restores(tmp_path, budget):
+    """A small forced budget sends what does not fit to the host in the
+    stall (both spans present, counter set) and restores bit for bit."""
+    state = _state(4)
+    m = AsyncCheckpointManager(str(tmp_path / "c"), ncf=2,
+                               cut_budget_bytes=budget)
+
+    def save():
+        m.save(1, state)
+        m.wait()
+    names = _cut_spans(save)
+    total = sum(np.asarray(x).nbytes for x in jax.tree.leaves(state))
+    host = m.telemetry()["cut_host_bytes"]
+    assert "ckpt.cut.host" in names and "ckpt.snapshot" in names
+    assert ("ckpt.cut.device" in names) == (budget > 0)
+    assert host == total - (budget if budget else 0)
+    got, _ = m.restore(_template(state), step=1)
+    m.close()
+    _assert_tree_equal(got, state, "budgeted cut ")
+
+
+def test_cut_is_donation_safe_on_both_paths(tmp_path):
+    """The state's buffers donated and overwritten right after ``save``
+    returns: the checkpoint still holds the values at the save, on the
+    device path and on the host path of the cut alike."""
+    want = jax.tree.map(np.array, _state(5))
+    bump = jax.jit(lambda s: jax.tree.map(lambda x: x + 1, s),
+                   donate_argnums=0)
+    for budget in (None, 96 * 32 * 4):
+        m = AsyncCheckpointManager(str(tmp_path / f"d{budget}"), ncf=2,
+                                   cut_budget_bytes=budget)
+        state = _state(5)
+        m.save(1, state)
+        state = bump(state)        # donates and overwrites the saved buffers
+        state = bump(state)
+        m.wait()
+        got, _ = m.restore(_template(want), step=1)
+        m.close()
+        _assert_tree_equal(got, want, f"budget={budget} ")
+        assert float(np.asarray(state["step"])) == 7
+
+
+def test_no_memory_figures_keeps_every_leaf_on_device(tmp_path):
+    """On a device without memory figures (the CPU) and no forced budget
+    the cut takes today's path: device copies only, nothing to the host."""
+    assert jax.devices()[0].memory_stats() is None
+    m = AsyncCheckpointManager(str(tmp_path / "n"), ncf=2)
+
+    def save():
+        m.save(1, _state(2))
+        m.wait()
+    names = _cut_spans(save)
+    assert "ckpt.cut.device" in names and "ckpt.cut.host" not in names
+    assert m.telemetry()["cut_host_bytes"] == 0
+    got, _ = m.restore(_template(_state(2)), step=1)
+    m.close()
+    _assert_tree_equal(got, _state(2), "device cut ")
+
+
+def test_cut_budget_does_not_ratchet_on_its_own_copies(tmp_path):
+    """The budget reads the steps' peak: a later peak raised only by the
+    cut's own device copies leaves the budget where it was."""
+    class Dev:
+        def __init__(self, peak, reserved=1_000 << 20):
+            self.peak, self.reserved = peak, reserved
+
+        def memory_stats(self):
+            return {"bytes_limit": 10_000 << 20,
+                    "peak_bytes_in_use": self.peak,
+                    "peak_bytes_reserved": self.reserved}
+    m = AsyncCheckpointManager(str(tmp_path / "r"), ncf=2)
+    try:
+        # live buffers and the programs' reserved temporaries both count
+        dev = Dev(5_000 << 20)
+        first = m._cut_budget(dev)
+        assert first == (4_000 << 20) - m.CUT_MARGIN
+        m._cut_device_max[dev] = first     # the cut filled its budget
+        dev.peak += first
+        assert m._cut_budget(dev) == first
+        # steps that grow beyond that do shrink it
+        dev.peak += 1_000 << 20
+        assert m._cut_budget(dev) == first - (1_000 << 20)
+        dev.peak -= 1_000 << 20
+        dev.reserved = 3_000 << 20
+        assert m._cut_budget(dev) == first - (2_000 << 20)
+    finally:
+        m.close()
+    # two devices: each budget reads its own device's peak and copies
+    m = AsyncCheckpointManager(str(tmp_path / "r2"), ncf=2)
+    try:
+        low, high = Dev(2_000 << 20), Dev(6_000 << 20)
+        b_low, b_high = m._cut_budget(low), m._cut_budget(high)
+        assert b_low == (7_000 << 20) - m.CUT_MARGIN
+        assert b_high == (3_000 << 20) - m.CUT_MARGIN
+        m._cut_device_max.update({low: b_low, high: b_high})
+        low.peak += b_low
+        high.peak += b_high
+        assert (m._cut_budget(low), m._cut_budget(high)) == (b_low, b_high)
+    finally:
+        m.close()
