@@ -11,14 +11,16 @@ multi-domain in-transit engine uses.
 
 Two backends register here, mirroring the ``insitu`` registry:
 
-  * ``thread``  — one daemon thread per group over a pooled
-    :class:`~repro.insitu.staging.StagingArea`; writes run in the
-    training process (simple, zero extra processes, the file-write
-    syscalls release the GIL).
+  * ``thread``  — one daemon thread per group over a
+    :class:`~repro.insitu.staging.StagingArea` that holds each payload
+    by reference (:class:`ReferenceStagingArea`: the gather's raw shard
+    is the pulled host buffer itself, never copied on the way); writes
+    run in the training process (simple, zero extra processes, the
+    file-write syscalls release the GIL).
   * ``process`` — one spawned OS process per group fed through a
     :class:`~repro.insitu.staging.ShmStagingArea` (shared-memory slabs,
-    pickle-free): serialization and page-cache writes leave the
-    producer's GIL entirely.
+    pickle-free, one copy of each payload into the slab): serialization
+    and page-cache writes leave the producer's GIL entirely.
 
 Both run ``policy="block"`` — checkpoints are lossless; backpressure
 stalls the *gather thread*, never the train step (the step only waits
@@ -78,6 +80,8 @@ class CkptLaneBackend:
     """
 
     name = ""
+    #: whether :meth:`push` stages the payload without copying it
+    stages_by_reference = False
 
     def __init__(self, manager, *, queue_capacity: int = 4):
         self.manager = manager
@@ -87,7 +91,10 @@ class CkptLaneBackend:
         self._lock = threading.Lock()
 
     def push(self, group: int, step: int, payload, desc: dict) -> None:
-        """Stage one encoded shard (uint8 payload + record descriptor)."""
+        """Stage one encoded shard (uint8 payload + record descriptor).
+
+        The caller never writes to ``payload`` afterwards: a backend that
+        :attr:`stages_by_reference` hands that very array to its lane."""
         self._area(group).push(step, {"payload": payload}, kind="ckpt",
                                meta=desc)
 
@@ -113,10 +120,40 @@ def _write_shard(db: HerculeDB, snap) -> list[Record]:
     return w.records
 
 
+class _HeldBuffers:
+    """Buffer set that stages the pushed host arrays themselves.
+
+    Holds nothing on ``self`` between fills: the snapshot owns the only
+    reference, and :meth:`ReferenceStagingArea.release` drops it, so
+    host memory does not grow with the pool."""
+
+    def fill(self, arrays: dict):
+        out = dict(arrays)
+        return out, 0, 0, sum(a.nbytes for a in out.values())
+
+
+class ReferenceStagingArea(StagingArea):
+    """:class:`StagingArea` that stages by reference, not by copy.
+
+    For producers whose pushed arrays are private and never written
+    again — the gather's payloads: a fresh host pull, a host-cut leaf or
+    a copied non-jax leaf, or a delta codec's output. The base area's
+    pooled copy is for producers that reuse or donate their arrays
+    after ``push``, as the HDep engine's do; the gather does neither.
+    """
+
+    BUFFER_SET = _HeldBuffers
+
+    def release(self, snap) -> None:
+        super().release(snap)
+        snap.arrays = {}
+
+
 class ThreadCkptLanes(CkptLaneBackend):
     """One in-process writer thread per contributor group."""
 
     name = "thread"
+    stages_by_reference = True
 
     def __init__(self, manager, *, queue_capacity: int = 4):
         super().__init__(manager, queue_capacity=queue_capacity)
@@ -126,9 +163,9 @@ class ThreadCkptLanes(CkptLaneBackend):
         with self._lock:
             area = self.stages.get(group)
             if area is None:
-                area = StagingArea(capacity=self.queue_capacity,
-                                   policy="block",
-                                   n_buffers=self.queue_capacity + 2)
+                area = ReferenceStagingArea(
+                    capacity=self.queue_capacity, policy="block",
+                    n_buffers=self.queue_capacity + 2)
                 t = threading.Thread(target=self._lane, args=(group, area),
                                      name=f"hprot-lane-g{group}",
                                      daemon=True)
@@ -137,7 +174,7 @@ class ThreadCkptLanes(CkptLaneBackend):
                 t.start()
             return area
 
-    def _lane(self, group: int, area: StagingArea) -> None:
+    def _lane(self, group: int, area: ReferenceStagingArea) -> None:
         mgr = self.manager
         while True:
             snap = area.pop(timeout=0.25)

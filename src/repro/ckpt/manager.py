@@ -19,7 +19,10 @@ stages, each its own span:
       CRC32-stamped and pushed into the owning contributor group's
       staging area; one child span each, in that order: ``ckpt.pull``,
       ``ckpt.encode``, ``ckpt.crc``, ``ckpt.enqueue`` (which blocks
-      while the group's lane is behind).
+      while the group's lane is behind). A raw shard is the pulled host
+      array's own bytes, CRC'd in place: a thread lane's staging area
+      holds it by reference (counter ``ckpt_zero_copy_bytes_total``), a
+      process lane's copies it once into shared memory.
   ``ckpt.write``     (writer lanes, thread or process) — append to the
       group's Hercule files and publish to the page cache
       (``flush_domain(sync=False)``); no fsync here.
@@ -150,6 +153,10 @@ class AsyncCheckpointManager:
             "ckpt_cut_host_bytes", "snapshot bytes cut to the host in the "
             "stall (the device had no room for their copies)")
         self._cut_host_bytes = 0
+        self._c_zero_copy = self.obs.counter(
+            "ckpt_zero_copy_bytes_total", "raw shard bytes staged to a "
+            "lane with no host copy")
+        self._zero_copy_bytes = 0
 
         self._backend = make_backend(lane_backend, self,
                                      queue_capacity=queue_capacity)
@@ -301,7 +308,7 @@ class AsyncCheckpointManager:
         keep_prev = self.delta_every > 0
         new_prev: dict | None = {} if keep_prev else None
         g0 = time.perf_counter()
-        count = 0
+        count = zero_copy = 0
         for entry in cut:
             name, domain, slices, gshape, data = entry
             domain = int(domain)
@@ -317,7 +324,7 @@ class AsyncCheckpointManager:
                     codec, payload, meta = self._encode(name, domain, host,
                                                         full=full)
                 with TRACER.span("ckpt.crc", cat="ckpt"):
-                    crc = zlib.crc32(payload) & 0xFFFFFFFF
+                    crc = zlib.crc32(payload) & 0xFFFFFFFF   # in place
                 # blocks while the owning group's lane is behind
                 with TRACER.span("ckpt.enqueue", cat="ckpt"):
                     desc = {
@@ -330,11 +337,12 @@ class AsyncCheckpointManager:
                         "_trace": pend.tctx,
                     }
                     self._backend.push(self.db.group_of(domain), step,
-                                       np.frombuffer(payload, np.uint8),
-                                       desc)
+                                       payload, desc)
             count += 1
+            if codec == "raw" and self._backend.stages_by_reference:
+                zero_copy += payload.nbytes
             if obs_metrics.ENABLED:
-                self._c_bytes.labels(codec).inc(len(payload))
+                self._c_bytes.labels(codec).inc(payload.nbytes)
                 self._c_records.inc()
             if keep_prev:
                 new_prev[(name, domain)] = host
@@ -351,15 +359,21 @@ class AsyncCheckpointManager:
         if obs_metrics.ENABLED:
             self._h_gather.observe(time.perf_counter() - g0)
             self._c_saves.labels(mode).inc()
+            self._c_zero_copy.inc(zero_copy)
         with self._lock:
             pend.attrs["mode"] = mode
             pend.expected = count
+            self._zero_copy_bytes += zero_copy
         self._try_commit()
 
     def _encode(self, name: str, domain: int, data: np.ndarray, *,
                 full: bool):
-        """(codec, payload, meta) for one shard; delta when it pays."""
-        raw = np.ascontiguousarray(data).tobytes()
+        """(codec, payload, meta) for one shard; delta when it pays.
+
+        ``payload`` is flat ``uint8``. A raw one is a view of ``data``'s
+        own bytes, no copy (``data`` is a private host array the gather
+        never writes to); ``reshape(-1).view`` also takes the bfloat16
+        and 0-d arrays that ``memoryview.cast`` refuses."""
         if not full:
             prev = self._prev.get((name, domain))
             if str(data.dtype) in _FLOATY and data.size >= 64 \
@@ -367,10 +381,10 @@ class AsyncCheckpointManager:
                     and prev.dtype == data.dtype:
                 dc = pyr.encode_delta(data, prev)
                 payload = codecs.encode_delta(dc)
-                if len(payload) < len(raw):
-                    return ("fpdelta-delta", payload,
+                if len(payload) < data.nbytes:
+                    return ("fpdelta-delta", np.frombuffer(payload, np.uint8),
                             {"pred_step": self._prev_step, "pad": dc.pad})
-        return "raw", raw, {}
+        return "raw", np.ascontiguousarray(data).reshape(-1).view(np.uint8), {}
 
     # -------------------------------------------------- lane-side reports
     def _shard_landed(self, step: int, group: int, records,
@@ -575,6 +589,7 @@ class AsyncCheckpointManager:
                     "errors": len(self._errors),
                     "stall_seconds_total": self._stall_total,
                     "cut_host_bytes": self._cut_host_bytes,
+                    "zero_copy_bytes": self._zero_copy_bytes,
                     "delta_every": self.delta_every,
                     "deltas_since_full": self._deltas_since_full,
                     "backend": self._backend.telemetry()}

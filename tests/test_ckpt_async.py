@@ -394,3 +394,109 @@ def test_cut_budget_does_not_ratchet_on_its_own_copies(tmp_path):
         assert (m._cut_budget(low), m._cut_budget(high)) == (b_low, b_high)
     finally:
         m.close()
+
+
+# ------------------------------------------- the raw shard's hand-off
+
+def _odd_state():
+    """bfloat16, 0-d and zero-size leaves beside a plain float32 one."""
+    return {"bf16": jnp.asarray(np.arange(96 * 32).reshape(96, 32) / 7.0,
+                                jnp.bfloat16),
+            "count": jnp.int32(-7),
+            "empty": jnp.zeros((0, 4), jnp.float32),
+            "w": jnp.asarray(np.arange(300, dtype=np.float32) / 3.0)}
+
+
+def _raw_bytes(state) -> int:
+    return sum(np.asarray(x).nbytes for x in jax.tree.leaves(state))
+
+
+def _zero_copy(m):
+    """The manager's zero-copy bytes, from telemetry and from its counter
+    (which must agree)."""
+    snap = m.obs.snapshot()["ckpt_zero_copy_bytes_total"]["samples"]
+    tel = m.telemetry()["zero_copy_bytes"]
+    assert snap[0]["value"] == tel
+    return tel
+
+
+def test_raw_shards_reach_thread_lanes_uncopied(tmp_path, monkeypatch):
+    """On thread lanes every raw payload the lane writes is the memory
+    the gather pulled to the host: no copy in the encode, none in the
+    staging area; the counter reads the state's raw bytes."""
+    from repro.ckpt import lanes
+    pulled, written = {}, {}
+    write_shard = lanes._write_shard
+
+    def spy_write(db, snap):
+        written[snap.meta["rec_name"]] = snap.arrays["payload"]
+        return write_shard(db, snap)
+    monkeypatch.setattr(lanes, "_write_shard", spy_write)
+    state = _odd_state()
+    m = AsyncCheckpointManager(str(tmp_path / "z"), ncf=2)
+    encode = m._encode
+
+    def spy_encode(name, domain, data, *, full):
+        pulled[f"ckpt/{name}"] = data
+        return encode(name, domain, data, full=full)
+    m._encode = spy_encode
+    m.save(1, state)
+    m.wait()
+    assert sorted(written) == sorted(pulled) and len(pulled) == 4
+    for name, host in pulled.items():
+        assert m.db.view(1).record(0, name).codec == "raw"
+        if host.nbytes:
+            assert np.shares_memory(written[name], host), name
+    assert _zero_copy(m) == _raw_bytes(state)
+    m.close()
+
+
+@pytest.mark.parametrize("backend", ["thread", "process"])
+def test_odd_leaves_restore_bitexact(tmp_path, backend):
+    """bfloat16, 0-d int32 and zero-size leaves go out raw and restore
+    bit for bit on both lane backends; only a thread lane holds the
+    payload by reference, a process lane copies it into shared memory."""
+    state = _odd_state()
+    m = AsyncCheckpointManager(str(tmp_path / backend), ncf=2,
+                               lane_backend=backend)
+    m.save(1, state)
+    m.wait()
+    got, _ = m.restore(_template(state), step=1)
+    assert _zero_copy(m) == (_raw_bytes(state) if backend == "thread"
+                             else 0)
+    m.close()
+    for k in state:
+        assert got[k].dtype == state[k].dtype and \
+            got[k].shape == state[k].shape, k
+    _assert_tree_equal(got, state, f"{backend} ")
+
+
+def test_delta_over_raw_picks_the_smaller_codec(tmp_path):
+    """A delta save over a raw one writes ``fpdelta-delta`` where the
+    residual is smaller than the raw bytes and raw where it is not
+    (fresh noise); only the raw shards count as zero-copy."""
+    rng = np.random.default_rng(11)
+
+    def state(step):
+        return {**_state(step),
+                "noise": jnp.asarray(rng.standard_normal(4096, np.float32))}
+    s1, s2 = state(1), state(2)
+    m = AsyncCheckpointManager(str(tmp_path / "d"), ncf=2, delta_every=1)
+    m.save(1, s1)
+    m.save(2, s2)
+    m.wait()
+    codecs = {r.name: r.codec for r in m.db.view(2).records}
+    assert codecs["ckpt/['params']['w']"] == "fpdelta-delta"
+    assert codecs["ckpt/['mu']['w']"] == "fpdelta-delta"
+    assert codecs["ckpt/['noise']"] == "raw"
+    assert codecs["ckpt/['step']"] == "raw"         # an integer leaf
+    delta = {"params", "mu"}
+    raw2 = sum(np.asarray(x).nbytes for k, v in s2.items()
+               if k not in delta for x in jax.tree.leaves(v))
+    raw2 += np.asarray(s2["params"]["b"]).nbytes    # 32 floats: < 64
+    assert _zero_copy(m) == _raw_bytes(s1) + raw2
+    tpl = _template(s1)
+    for step, want in ((1, s1), (2, s2)):
+        got, _ = m.restore(tpl, step=step)
+        _assert_tree_equal(got, want, f"step {step} ")
+    m.close()

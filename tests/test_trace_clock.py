@@ -120,8 +120,12 @@ def test_ring_full_wait_and_upload_are_apart(tracing):
 
 
 def test_checkpoint_stage_has_its_four_parts(tracing, tmp_path):
-    # tensors large enough that the spans' own bookkeeping between the
-    # parts (a few hundred microseconds a tensor) stays under 1%
+    # the stage's time outside its parts is the spans' own bookkeeping,
+    # a few hundred microseconds a tensor however short the parts are
+    # (on the raw path only the CRC is long): an absolute budget of
+    # 5 ms a tensor, summed over the tensors so that one scheduling
+    # hiccup on a loaded host does not decide it. At 64 MB a tensor,
+    # one byte copy of a tensor outside the parts overruns it.
     rng = np.random.default_rng(3)
     state = {f"w{i}": jnp.asarray(rng.standard_normal((4096, 4096),
                                                       np.float32))
@@ -135,13 +139,15 @@ def test_checkpoint_stage_has_its_four_parts(tracing, tmp_path):
     kids: dict = {}
     for s in TRACER.spans():
         kids.setdefault(s["parent_id"], []).append(s)
+    outside = 0
     for st in stages:
         parts = sorted(kids[st["span_id"]], key=lambda s: s["ts"])
         assert [p["name"] for p in parts] == [
             "ckpt.pull", "ckpt.encode", "ckpt.crc", "ckpt.enqueue"]
         for a, b in zip(parts, parts[1:]):
             assert a["ts"] + a["dur"] <= b["ts"]
-        assert sum(p["dur"] for p in parts) >= 0.99 * st["dur"]
+        outside += st["dur"] - sum(p["dur"] for p in parts)
+    assert outside < 5_000 * len(stages)                     # µs
 
 
 def test_compile_span_opens_under_the_current_span(tracing):
