@@ -61,10 +61,11 @@ from ..core import pyramid as pyr
 from ..hercule import api, codecs
 from ..hercule.checkpoint import _FLOATY, _leaf_paths, _slices_json
 from ..hercule.database import HerculeDB
+from ..insitu.lanes import Lanes, check_mode
 from ..obs import events as obs_events
 from ..obs import metrics as obs_metrics
 from ..obs.trace import TRACER
-from .lanes import make_backend
+from .lanes import HProtJob, ReferenceStagingArea
 from .restore import latest_complete_step, verified_reader
 
 _SENTINEL = object()
@@ -107,6 +108,7 @@ class AsyncCheckpointManager:
         """``cut_budget_bytes`` forces the snapshot's device-copy budget
         (tests only); by default it comes from the device's memory
         figures (``_cut_budget``)."""
+        check_mode(lane_backend)      # before creating anything on disk
         self.db = HerculeDB.create(root, kind="hprot", ncf=ncf,
                                    max_file_bytes=max_file_bytes,
                                    io_threads=io_threads)
@@ -158,8 +160,12 @@ class AsyncCheckpointManager:
             "lane with no host copy")
         self._zero_copy_bytes = 0
 
-        self._backend = make_backend(lane_backend, self,
-                                     queue_capacity=queue_capacity)
+        #: the writer lanes, one per contributor group, made on the
+        #: first push to it (see insitu.lanes)
+        self._backend = Lanes(
+            HProtJob(self), lane_backend, root=self.db.root,
+            queue_capacity=max(1, int(queue_capacity)), policy="block",
+            area_cls=ReferenceStagingArea).start()
         # depth-1 hand-off: a save whose *predecessor* is still
         # gathering blocks — the paper's barrier on the previous flush
         self._q: queue.Queue = queue.Queue(maxsize=1)
@@ -336,10 +342,11 @@ class AsyncCheckpointManager:
                                      "crc32": int(crc)},
                         "_trace": pend.tctx,
                     }
-                    self._backend.push(self.db.group_of(domain), step,
-                                       payload, desc)
+                    area = self._backend.area(self.db.group_of(domain))
+                    area.push(step, {"payload": payload}, kind="ckpt",
+                              meta=desc)
             count += 1
-            if codec == "raw" and self._backend.stages_by_reference:
+            if codec == "raw" and self._backend.mode == "thread":
                 zero_copy += payload.nbytes
             if obs_metrics.ENABLED:
                 self._c_bytes.labels(codec).inc(payload.nbytes)

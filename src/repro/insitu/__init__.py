@@ -19,9 +19,10 @@ written at an independent cadence.
   * :mod:`reducers`  — composable reduction operators over AMR trees and
     train states, combined in a DAG; each declares its multi-domain
     merge strategy.
-  * :mod:`lanes`     — the pluggable lane runtime: ``thread`` lanes
-    (in-process workers) or ``process`` lanes (one OS process per
-    contributor group over shared-memory staging).
+  * :mod:`lanes`     — the lane runtime under both the engine and the
+    async checkpoint manager: ``thread`` lanes (in-process workers) or
+    ``process`` lanes (one OS process per contributor group over
+    shared-memory staging).
   * :mod:`engine`    — per-group lanes consuming staged snapshots and
     writing reduced HDep domains at the engine's own output frequency.
   * :mod:`device`    — on-accelerator reduction: device-resident staging
@@ -44,8 +45,6 @@ written at an independent cadence.
 """
 from .catalog import Catalog                                   # noqa: F401
 from .engine import InTransitEngine                            # noqa: F401
-from .lanes import (BACKENDS, LANE_POOL, LaneBackend,          # noqa: F401
-                    register_backend, shutdown_pool)
 from .partition import partition_snapshot                      # noqa: F401
 from .reducers import (LevelHistogramReducer, LODCutReducer,   # noqa: F401
                        ProjectionReducer, Reducer, ReducerDAG,

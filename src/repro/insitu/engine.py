@@ -18,12 +18,12 @@ backpressure); reads merge the domains back
 (``hercule.api.ReducedKind``), so a context with some parts dropped
 still serves its surviving domains.
 
-*How* lanes execute is pluggable (``insitu.lanes``): ``backend="thread"``
-keeps every lane an in-process worker thread (PR-3 semantics, bit for
-bit); ``backend="process"`` makes each group's lane an OS process fed
-through shared-memory staging, so reduction and domain writes run
-outside the producer's GIL — the live pipeline scales the way
-``bench_insitu.run_multidomain`` demonstrates with separate processes.
+*How* lanes execute is the lane runtime's (``insitu.lanes``), which
+the async checkpoint manager shares; this module gives it HDep's job
+(:class:`_HDepJob`). ``backend="thread"`` keeps every lane an
+in-process worker thread; ``backend="process"`` makes each group's lane
+an OS process fed through shared-memory staging, so reduction and
+domain writes run outside the producer's GIL.
 
 ``step_ttl`` bounds the life of a partial step: when per-producer
 submission (:meth:`submit_part`) loses a producer (crash, skipped
@@ -44,14 +44,14 @@ import time
 
 from ..core.amr import AMRTree
 from ..hercule import api
-from ..hercule.database import HerculeDB
+from ..hercule.database import DomainWriter, HerculeDB
 from ..obs import events as obs_events
 from ..obs import metrics as obs_metrics
-from ..obs.trace import TRACER
-from .lanes import make_backend
+from ..obs.trace import TRACER, now_us
+from .lanes import LaneJob, LaneMsg, Lanes, check_mode
 from .partition import partition_snapshot
 from .reducers import Reducer, ReducerDAG
-from .staging import Snapshot
+from .staging import Snapshot, StagingArea
 
 
 @dataclasses.dataclass
@@ -79,12 +79,8 @@ class InTransitEngine:
                  durable_parts: bool = False, backend: str = "thread",
                  step_ttl: float | None = None,
                  device_reduce: bool | str = False,
-                 mesh_devices: int | None = None,
-                 lane_pool: bool = False, ledger=None):
-        from .lanes import BACKENDS
-        if backend not in BACKENDS:   # before creating anything on disk
-            raise ValueError(f"unknown lane backend {backend!r}; "
-                             f"registered: {sorted(BACKENDS)}")
+                 mesh_devices: int | None = None, ledger=None):
+        check_mode(backend)           # before creating anything on disk
         self.n_domains = max(1, domains)
         if isinstance(device_reduce, str) and device_reduce != "mesh":
             raise ValueError(
@@ -103,27 +99,30 @@ class InTransitEngine:
                 f"device_reduce={self.device_reduce!r} requires "
                 f"backend='thread' (device arrays and the device mesh "
                 f"stay in the engine process)")
-        if lane_pool and backend != "process":
-            raise ValueError(
-                "lane_pool=True only applies to backend='process' "
-                "(thread lanes have no spawn cost to amortize)")
         if backend == "process" and self.n_domains > 1:
             ncf = 1   # each lane process must own its group files
         self.db = root if isinstance(root, HerculeDB) else \
             HerculeDB.create(root, kind="hdep", ncf=ncf)
+        if backend == "process" and self.n_domains > 1 and self.db.ncf != 1:
+            raise ValueError(
+                f"backend='process' needs one Hercule group per domain so "
+                f"each lane owns its files; database has ncf={self.db.ncf} "
+                f"(create the engine with ncf=1)")
         self.dag = ReducerDAG(reducers)
         #: device-reduce runner (None = host DAG execution); staging
-        #: residency follows it — see lanes.ThreadLaneBackend
+        #: residency follows it
         self._device = None
+        area_cls = StagingArea
         if self.device_reduce == "mesh":
             # sharded path: snapshots stage on *host* (the leaf table is
-            # Hilbert-sharded over the mesh at reduce time), so the
-            # staging area stays the plain host one — see lanes
+            # Hilbert-sharded over the mesh at reduce time), so a single
+            # device-resident copy would only add a pointless hop
             from .mesh_reduce import MeshDAGRunner
             self._device = MeshDAGRunner(self.dag, devices=mesh_devices)
         elif self.device_reduce:
-            from .device import DeviceDAGRunner
+            from .device import DeviceDAGRunner, DeviceStagingArea
             self._device = DeviceDAGRunner(self.dag)
+            area_cls = DeviceStagingArea
         self.compress = compress
         self.output_every = max(1, output_every)
         #: fsync each group file from its own lane right after the part
@@ -148,12 +147,13 @@ class InTransitEngine:
         self._started = False
         #: the lane runtime: staging transport + execution context per
         #: contributor group (see insitu.lanes)
-        self._backend = make_backend(backend, self, workers=workers,
-                                     queue_capacity=queue_capacity,
-                                     policy=policy, lane_pool=lane_pool)
+        self._backend = Lanes(_HDepJob(self), backend, root=self.db.root,
+                              queue_capacity=queue_capacity, policy=policy,
+                              workers=workers, area_cls=area_cls,
+                              on_evict=self._on_evict)
         #: one staging area per contributor group; ``staging`` aliases
         #: group 0 for the single-group API the compute side always had
-        self.stages = self._backend.stages
+        self.stages = [self._backend.area(g) for g in range(self.n_domains)]
         self.staging = self.stages[0]
         #: per-engine metrics registry (engine instances never collide);
         #: hot-path observes are gated on obs.metrics.ENABLED, callback
@@ -182,7 +182,7 @@ class InTransitEngine:
 
     @property
     def backend(self) -> str:
-        return self._backend.name
+        return self._backend.mode
 
     # ------------------------------------------------------------ run ledger
     def bind_ledger(self, ledger) -> None:
@@ -608,7 +608,10 @@ class InTransitEngine:
             with TRACER.span("manifest.commit", parent=pend.trace,
                              args={"step": step,
                                    "domains": sorted(pend.wrote)}):
-                self._backend.pre_finalize(pend)
+                if self._backend.mode == "process" and pend.ctx.records:
+                    # lanes flushed their appends to the page cache; make
+                    # exactly the files this manifest references durable
+                    self.db.fsync_files(r.file for r in pend.ctx.records)
                 pend.ctx.finalize(attrs={"insitu": {
                     "kind": pend.kind,
                     "reducers": sorted(pend.reducers),
@@ -703,7 +706,7 @@ class InTransitEngine:
                   else None for a in self.stages]   # None once unlinked
         lanes.update(self._backend.telemetry())
         return {
-            "backend": self._backend.name,
+            "backend": self._backend.mode,
             "staging": {"per_group": per_group, "totals": totals,
                         "queued": queued},
             "lanes": lanes,
@@ -793,3 +796,99 @@ class InTransitEngine:
         if err is not None:
             raise err
         self.check_errors()
+
+
+def _reduce_in_lane(db, group, snap, tracer, t_pop, dag, compress,
+                    durable_parts) -> LaneMsg:
+    """Process-lane work: reduce one part and append it to the group's
+    own files; the engine commits the manifest from the records."""
+    r0 = now_us()
+    outputs = dag.run(snap)
+    r1 = now_us()
+    if not outputs:
+        return LaneMsg("skipped", snap.step, group)
+    ctx = DomainWriter(db, snap.step)
+    w0 = now_us()
+    for rname, arrays in outputs.items():
+        api.write_object(ctx, "reduced", group, arrays, reducer=rname,
+                         compress=compress)
+    # publish the appended bytes: page cache always (the manifest
+    # committer fsyncs by path), disk if this lane owns its durability
+    db.flush_domain(group, sync=durable_parts)
+    w1 = now_us()
+    tctx = snap.meta.get("_trace")
+    if tctx is not None:
+        # cross-process parent linkage: no clock sync beyond the epoch
+        args = {"step": snap.step, "group": group}
+        tracer.record("stage.pop", t_pop, r0, parent=tctx, args=args)
+        tracer.record("reduce", r0, r1, parent=tctx, args=args)
+        tracer.record("write", w0, w1, parent=tctx, args=args)
+    return LaneMsg("done", snap.step, group, records=ctx.records,
+                   timings=((r1 - r0) / 1e6, (w1 - w0) / 1e6),
+                   extras=(sorted(outputs), snap.kind, snap.meta))
+
+
+class _HDepJob(LaneJob):
+    """HDep's lane work: reduce each staged part and land it as the
+    group's domain of the step's context."""
+
+    label = "insitu"
+    lane_work = staticmethod(_reduce_in_lane)
+
+    def __init__(self, engine: InTransitEngine):
+        self.engine = engine
+        self.errors = engine._errors
+        self.lane_args = (engine.dag, engine.compress, engine.durable_parts)
+
+    @property
+    def ledger(self):
+        return self.engine.ledger
+
+    def work(self, group, snap, t_pop):
+        eng = self.engine
+        tctx = snap.meta.get("_trace")
+        if tctx is not None and t_pop:
+            # dequeue latency: staged -> picked up by this lane (not
+            # when the tracer came on during the wait: no start)
+            TRACER.record("stage.pop", t_pop, now_us(), parent=tctx,
+                          args={"step": snap.step, "group": snap.domain})
+        try:
+            eng._reduce_and_write(snap)
+        except BaseException as e:   # surfaced on next submit/drain
+            self.failed(snap.domain, e, step=snap.step)
+
+    def idle(self):
+        self.engine._run_deferred()
+        self.engine._sweep_ttl()
+
+    def settle(self, msg):
+        eng = self.engine
+        if msg.tag == "skipped":
+            with eng._wlock:
+                eng._skipped += 1
+            eng._part_done(msg.step, None, None)
+            return
+        if obs_metrics.ENABLED:
+            eng._h_reduce.labels(msg.group).observe(msg.timings[0])
+            eng._h_write.labels(msg.group).observe(msg.timings[1])
+        reducers, kind, meta = msg.extras
+        eng._part_records(msg.step, msg.group, msg.records, set(reducers),
+                          kind, meta)
+
+    def failed(self, group, exc, *, step=None, exitcode=None):
+        eng = self.engine
+        eng._errors.append(exc)
+        if exitcode is not None:
+            # flight recorder: a SIGKILLed lane reports nothing, so the
+            # engine writes the crash event on its behalf and forces a
+            # durable ledger flush with whatever partial attribution the
+            # dead lane's steps have
+            obs_events.EVENTS.emit(obs_events.LANE_CRASH, group=group,
+                                   exitcode=exitcode)
+            obs_events.EVENTS.dump("lane.crash", group=group,
+                                   exitcode=exitcode)
+            return
+        with eng._wlock:
+            eng._failed += 1
+        if step >= 0:
+            eng._part_done(step, None, None)

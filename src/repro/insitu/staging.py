@@ -599,13 +599,12 @@ class ShmStagingArea:
     may be an OS process. The parent constructs it and pushes; a lane
     process calls :meth:`attach` on :meth:`handle` and pops. ``close``
     only signals; :meth:`unlink` reclaims the segments once every
-    consumer detached (the owning backend calls it after joining lanes).
+    consumer detached (the lane runtime calls it after joining lanes).
     """
 
     def __init__(self, *, capacity: int = 4, policy: str = "drop-oldest",
                  n_slots: int | None = None, on_evict=None,
-                 min_slot_bytes: int = 1 << 16, mp_context=None,
-                 sync=None):
+                 min_slot_bytes: int = 1 << 16, mp_context=None):
         from multiprocessing import shared_memory
         assert policy in POLICIES, policy
         assert capacity >= 1
@@ -620,15 +619,9 @@ class ShmStagingArea:
             create=True,
             size=(4 + 6 * n + N_STAT_WORDS + N_CTRL_WORDS) * 8,
             name=f"{self._uid}ctl")
-        if sync is not None:
-            # externally owned primitives (the persistent lane pool:
-            # a pooled lane inherited them at spawn, long before this
-            # area existed — see insitu.lanes.LanePool)
-            self._lock, self._not_empty, self._not_full = sync
-        else:
-            self._lock = ctx.Lock()
-            self._not_empty = _CrashSafeCondition(self._lock, ctx)
-            self._not_full = _CrashSafeCondition(self._lock, ctx)
+        self._lock = ctx.Lock()
+        self._not_empty = _CrashSafeCondition(self._lock, ctx)
+        self._not_full = _CrashSafeCondition(self._lock, ctx)
         self._bind(self._shm, n)
         self._words[:] = 0
         self._words[3] = n
@@ -669,29 +662,6 @@ class ShmStagingArea:
                          n_slots=self.n_slots, capacity=self.capacity,
                          lock=self._lock, not_empty=self._not_empty,
                          not_full=self._not_full)
-
-    def spec(self) -> dict:
-        """Primitive-free attach spec (queue-transportable).
-
-        ``multiprocessing`` locks/conditions only pickle during process
-        *creation* — a handle sent over a queue to an already-running
-        pooled lane must not carry them. The lane rebuilds a full
-        :class:`ShmHandle` from this spec plus the sync primitives it
-        inherited at spawn (the same objects this area was constructed
-        with via ``sync=``; see ``insitu.lanes.LanePool``).
-        """
-        return {"uid": self._uid, "pid": os.getpid(),
-                "control": self._shm.name, "n_slots": self.n_slots,
-                "capacity": self.capacity}
-
-    @staticmethod
-    def handle_from_spec(spec: dict, sync) -> ShmHandle:
-        """Rebuild an attachable handle from :meth:`spec` + inherited sync."""
-        lock, not_empty, not_full = sync
-        return ShmHandle(uid=spec["uid"], pid=spec["pid"],
-                         control=spec["control"], n_slots=spec["n_slots"],
-                         capacity=spec["capacity"], lock=lock,
-                         not_empty=not_empty, not_full=not_full)
 
     @classmethod
     def attach(cls, handle: ShmHandle) -> "ShmStagingArea":

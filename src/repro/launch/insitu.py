@@ -67,9 +67,6 @@ def main(argv=None):
                         "on-device merge tree (0 = off; on CPU force "
                         "devices with XLA_FLAGS="
                         "--xla_force_host_platform_device_count=N)")
-    p.add_argument("--lane-pool", action="store_true",
-                   help="with --backend process: borrow lanes from the "
-                        "persistent module pool instead of spawning")
     p.add_argument("--queries", type=int, default=16,
                    help="viewer queries to replay against the catalog")
     p.add_argument("--serve-check", action="store_true",
@@ -111,8 +108,7 @@ def main(argv=None):
         queue_capacity=args.queue_capacity, policy=args.policy,
         domains=args.domains, backend=args.backend,
         device_reduce=device_reduce,
-        mesh_devices=args.device_mesh or None,
-        lane_pool=args.lane_pool, ledger=ledger).start()
+        mesh_devices=args.device_mesh or None, ledger=ledger).start()
 
     print(f"== compute flow: {args.steps} Sedov steps "
           f"(policy={args.policy}, output_every={args.output_every}, "
@@ -183,9 +179,6 @@ def main(argv=None):
               f"{lt['steps_attributed']} steps attributed, "
               f"verdict={verdict} -> {args.out}/telemetry/ "
               f"(python -m repro.launch.obs report {args.out})")
-    if args.lane_pool:
-        from ..insitu import shutdown_pool
-        shutdown_pool()       # reclaim the resident lanes before exit
     if args.trace_out:
         from ..obs import TRACER
         n_spans = TRACER.write_chrome_trace(args.trace_out)

@@ -1,4 +1,4 @@
-"""Pluggable lane runtime + server-mode catalog (PR 4 acceptance).
+"""Lane runtime + server-mode catalog.
 
 Covers: thread/process backend parity (same steps in, byte-identical
 merged reads out), shared-memory slab reclamation on release()/close(),
@@ -11,10 +11,12 @@ import time
 import numpy as np
 import pytest
 
-from repro.insitu import (BACKENDS, Catalog, CatalogServer, InTransitEngine,
+from repro.ckpt import AsyncCheckpointManager
+from repro.insitu import (Catalog, CatalogServer, InTransitEngine,
                           LevelHistogramReducer, LODCutReducer,
                           ProjectionReducer, RemoteCatalog, ShmStagingArea,
                           SliceReducer, TensorNormReducer)
+from repro.insitu.lanes import MODES
 from repro.insitu.partition import partition_snapshot
 from repro.insitu.staging import _attach_shm
 from repro.sim import amrgen, fields
@@ -39,11 +41,28 @@ def _reducers():
 
 # ----------------------------------------------------- backend registry
 
-def test_backend_registry(tmp_path):
-    assert set(BACKENDS) >= {"thread", "process"}
+_OWNERS = {
+    "engine": lambda root, mode: InTransitEngine(root, [], backend=mode),
+    "manager": lambda root, mode: AsyncCheckpointManager(
+        root, lane_backend=mode),
+}
+
+
+@pytest.mark.parametrize("owner", sorted(_OWNERS))
+def test_backend_registry(tmp_path, owner):
+    """Both lane owners take exactly the runtime's two modes, and refuse
+    any other name before touching the disk."""
+    make = _OWNERS[owner]
+    assert MODES == ("thread", "process")
+    for mode in MODES:
+        obj = make(str(tmp_path / mode), mode)
+        assert obj._backend.mode == mode
+        obj.close()
     root = tmp_path / "db"
-    with pytest.raises(ValueError, match="unknown lane backend"):
-        InTransitEngine(str(root), [], backend="warp-drive")
+    with pytest.raises(ValueError,
+                       match="unknown lane backend 'warp-drive'.*"
+                             "'thread', 'process'"):
+        make(str(root), "warp-drive")
     assert not root.exists()   # validated before touching the disk
 
 
@@ -378,35 +397,6 @@ def test_drain_timeout_still_raises(tmp_path):
     assert eng.submit_part(1, 1, parts[1])
     eng.close()
     assert eng.written_steps == [1]
-
-
-# --------------------------------------------------- persistent lane pool
-
-def test_lane_pool_reuses_spawned_lanes(tmp_path, sedov_tree):
-    """lane_pool=True: a second engine borrows the first engine's lane
-    processes (same PIDs) instead of paying spawn+import again, and the
-    reduced catalogs come out correct both times."""
-    from repro.insitu import shutdown_pool
-    from repro.insitu.lanes import LANE_POOL
-    pids = []
-    try:
-        for i in range(2):
-            root = str(tmp_path / f"db{i}")
-            eng = InTransitEngine(root, _reducers(), domains=2,
-                                  backend="process", lane_pool=True,
-                                  ncf=1).start()
-            assert eng.submit(1, sedov_tree)
-            eng.close()
-            pids.append(tuple(p.pid for p in eng._backend._procs))
-            cat = Catalog(root)
-            assert cat.steps() == [1]
-            assert cat.domains(1, _reducers()[2].name) == [0, 1]
-            cat.close()
-        assert pids[0] == pids[1]           # lanes actually reused
-        assert 2 in LANE_POOL._free and LANE_POOL._free[2]
-    finally:
-        shutdown_pool()
-    assert not LANE_POOL._free
 
 
 # ------------------------------------------------- server auth + ETag
