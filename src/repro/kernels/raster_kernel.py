@@ -19,11 +19,22 @@ for bit; non-pow2 resolutions take the host fallback in
 Kernel shape: the grid walks leaf blocks *sequentially* while the full
 output image (or histogram) stays resident in VMEM across grid steps
 (constant ``index_map``, initialized on the first step). Inside a block
-the slice/projection kernels ``fori_loop`` over the table's rows,
-reading each row's scalars from ``(1, BLOCK_N)`` tables in SMEM (vector
-memory cannot serve a per-leaf dynamic lane index). A row whose ``ok``
-is 0 (interior node, padding, unowned leaf, a leaf the slice plane
-misses) does no vector work. A painting row visits only the image
+the slice/projection kernels walk only the rows that paint, reading
+each row's scalars from ``(1, BLOCK_N)`` tables in SMEM (vector memory
+cannot serve a per-leaf dynamic lane index). The walk is a
+``while_loop`` over a block-relative next-painting-row column
+(:func:`next_painting_rows`, built from ``ok`` before the Pallas call):
+it starts at ``nxt[0]``, paints row ``i`` and goes on to ``nxt[i + 1]``
+until the block ends, so a row whose ``ok`` is 0 (interior node,
+padding, unowned leaf, a leaf the slice plane misses) costs no loop
+trip. The walk visits the painting rows in ascending row order, the
+order the former pass over every row painted them in, so the per-pixel
+BFS order below is unchanged. It is a skip column rather than a table
+compacted to the painting rows: the column is one reverse cumulative
+minimum per table, where compaction needs a sort or a scatter of every
+row, and the tables keep their shapes, grid and BlockSpecs (a tile cut
+at block boundaries, as the tiled carry path cuts, keeps its column
+valid). A painting row visits only the image
 tiles its rectangle covers: tiles of ``(min(8, R), min(128, R))``,
 one vreg, from the row's own ``u0``, ``v0``, ``px``
 (:func:`_tile_window`; a leaf of 8 px or less sits in one tile), each
@@ -33,8 +44,9 @@ same leaves, in BFS row order, with the same operation as a pass over
 the whole image, so per-pixel update *order* equals the host reducers'
 BFS traversal and the results are bit for bit those of the whole-image
 form. :func:`footprint_tiles` counts the rows that paint and the tiles
-they visit, on the host. At R=512 the carry kernels' planes fit the
-default scoped VMEM, so no ``vmem_limit_bytes`` is set. The histogram
+they visit, on the host; :func:`walked_rows` replays the walk there. At
+R=512 the carry kernels' planes fit the default scoped VMEM, so no
+``vmem_limit_bytes`` is set. The histogram
 kernel is fully vectorized: leaves ride the lane axis, a (B+1, BLOCK)
 edge-compare against float64 edges split into float32 pairs
 (:func:`split_edges`) reproduces ``np.searchsorted(edges, v,
@@ -223,31 +235,83 @@ def footprint_tiles(u0, v0, px, ok, resolution: int) -> tuple[int, int]:
     return int(sel.sum()), int((nr * nc).sum())
 
 
-def _slice_body(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
+def next_painting_rows(ok, block_n: int):
+    """Block-relative next-painting-row column of a padded (1, N) table.
+
+    ``nxt[b·block_n + i]`` is the smallest ``j >= i`` of block ``b``
+    whose row paints (``ok`` != 0), or ``block_n`` where none does: a
+    reverse cumulative minimum, along each block, of the row's own
+    in-block index (``block_n`` where it paints nothing). Returned as
+    (1, N) int32, the shape and SMEM block ``ok`` had; a cut at block
+    boundaries leaves it valid.
+    """
+    n = ok.shape[-1]
+    assert n % block_n == 0, f"N={n} not padded to {block_n}"
+    own = jnp.where(ok.reshape(n) != 0,
+                    jnp.arange(n, dtype=jnp.int32) % block_n, block_n)
+    return jax.lax.cummin(own.reshape(n // block_n, block_n), axis=1,
+                          reverse=True).reshape(1, n)
+
+
+def walked_rows(nxt, block_n: int) -> np.ndarray:
+    """Table rows the kernels' walk visits, one per loop trip, in order.
+
+    :func:`_walk_rows` replayed on the host over a
+    :func:`next_painting_rows` column: the kernels' loop trips for a
+    table are ``len(walked_rows(...))``.
+    """
+    rows = []
+    for b, col in enumerate(np.asarray(nxt).reshape(-1, block_n)):
+        i = int(col[0])
+        while i < block_n:
+            rows.append(b * block_n + i)
+            j = int(col[i + 1]) if i + 1 < block_n else block_n
+            if j <= i:
+                raise ValueError(f"row {b * block_n + i}: next row {j} "
+                                 "does not advance the walk")
+            i = j
+    return np.asarray(rows, np.int64)
+
+
+def _walk_rows(nxt_ref, paint, *, block_n: int):
+    """Call ``paint(i)`` on each painting row of the block, in row order.
+
+    One loop trip per painting row: from row ``i`` the walk goes to
+    ``nxt[i + 1]``, past every row between that paints nothing.
+    """
+    def step(i):
+        # read the next row before painting, so its load overlaps the paint
+        j = i + 1
+        # the block's last row has no next: its clamped read is dropped
+        nxt = jnp.where(j < block_n, nxt_ref[0, jnp.minimum(j, block_n - 1)],
+                        block_n)
+        paint(i)
+        return nxt
+
+    jax.lax.while_loop(lambda i: i < block_n, step, nxt_ref[0, 0])
+
+
+def _slice_body(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, nxt_ref,
                 img_ref, depth_ref, *, block_n: int, resolution: int):
-    def body(i, _):
-        @pl.when(ok_ref[0, i] != 0)
-        def _paint():
-            lvl, val = lvl_ref[0, i], val_ref[0, i]
+    def paint(i):
+        lvl, val = lvl_ref[0, i], val_ref[0, i]
 
-            def update(rows, cols, rect):
-                # deepest leaf wins; equal level repaints (leaves arrive
-                # in BFS order, so this is exactly the host painter's
-                # later-overrides)
-                depth = depth_ref[rows, cols]
-                mask = rect & (lvl >= depth)
-                img_ref[rows, cols] = jnp.where(mask, val,
-                                                img_ref[rows, cols])
-                depth_ref[rows, cols] = jnp.where(mask, lvl, depth)
+        def update(rows, cols, rect):
+            # deepest leaf wins; equal level repaints (leaves arrive in
+            # BFS order, so this is exactly the host painter's
+            # later-overrides)
+            depth = depth_ref[rows, cols]
+            mask = rect & (lvl >= depth)
+            img_ref[rows, cols] = jnp.where(mask, val, img_ref[rows, cols])
+            depth_ref[rows, cols] = jnp.where(mask, lvl, depth)
 
-            _paint_tiles(u0_ref[0, i], v0_ref[0, i], px_ref[0, i], update,
-                         resolution=resolution)
-        return 0
+        _paint_tiles(u0_ref[0, i], v0_ref[0, i], px_ref[0, i], update,
+                     resolution=resolution)
 
-    jax.lax.fori_loop(0, block_n, body, 0)
+    _walk_rows(nxt_ref, paint, block_n=block_n)
 
 
-def _slice_kernel(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
+def _slice_kernel(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, nxt_ref,
                   img_ref, depth_ref, *, block_n: int, resolution: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -255,11 +319,11 @@ def _slice_kernel(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
                                 img_ref.dtype)
         depth_ref[...] = jnp.full((resolution, resolution), -1, jnp.int32)
 
-    _slice_body(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
+    _slice_body(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, nxt_ref,
                 img_ref, depth_ref, block_n=block_n, resolution=resolution)
 
 
-def _slice_carry_kernel(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
+def _slice_carry_kernel(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, nxt_ref,
                         img0_ref, depth0_ref, img_ref, depth_ref, *,
                         block_n: int, resolution: int):
     """Slice kernel seeded from a carried (image, depth) pair.
@@ -273,7 +337,7 @@ def _slice_carry_kernel(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
         img_ref[...] = img0_ref[...]
         depth_ref[...] = depth0_ref[...]
 
-    _slice_body(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, ok_ref,
+    _slice_body(u0_ref, v0_ref, px_ref, lvl_ref, val_ref, nxt_ref,
                 img_ref, depth_ref, block_n=block_n, resolution=resolution)
 
 
@@ -284,8 +348,10 @@ def slice_raster(u0, v0, px, lvl, val, ok, *, resolution: int,
     """Rasterize the slice from a padded (1, N) leaf table.
 
     ``ok`` already folds leaf/owner/plane-hit/padding; N must be a
-    multiple of ``block_n`` (ops.py pads). Returns the (R, R) image
-    (deepest-covering-leaf semantics, NaN where uncovered).
+    multiple of ``block_n`` (ops.py pads). The kernel reads ``ok`` as
+    its :func:`next_painting_rows` column and walks only the rows that
+    paint. Returns the (R, R) image (deepest-covering-leaf semantics,
+    NaN where uncovered).
     """
     n = u0.shape[-1]
     assert n % block_n == 0, f"N={n} not padded to {block_n}"
@@ -304,7 +370,7 @@ def slice_raster(u0, v0, px, lvl, val, ok, *, resolution: int,
         ],
         name="slice_raster",
         interpret=interpret,
-    )(u0, v0, px, lvl, val, ok)
+    )(u0, v0, px, lvl, val, next_painting_rows(ok, block_n))
     return img
 
 
@@ -338,51 +404,48 @@ def slice_raster_carry(u0, v0, px, lvl, val, ok, img0, depth0, *,
         ],
         name="slice_raster_carry",
         interpret=interpret,
-    )(u0, v0, px, lvl, val, ok, img0, depth0)
+    )(u0, v0, px, lvl, val, next_painting_rows(ok, block_n), img0, depth0)
     return img, depth
 
 
 # ------------------------------------------------------- projection kernel
 
-def _proj_body(u0_ref, v0_ref, px_ref, contrib_ref, ok_ref, img_ref, *,
+def _proj_body(u0_ref, v0_ref, px_ref, contrib_ref, nxt_ref, img_ref, *,
                block_n: int, resolution: int):
-    def body(i, _):
-        @pl.when(ok_ref[0, i] != 0)
-        def _paint():
-            contrib = contrib_ref[0, i]
+    def paint(i):
+        contrib = contrib_ref[0, i]
 
-            def update(rows, cols, rect):
-                # where-guarded add: pixels outside the rectangle are
-                # untouched (no +0.0), and per-pixel adds run in BFS leaf
-                # order — the same float accumulation sequence as the
-                # host reducer
-                img = img_ref[rows, cols]
-                img_ref[rows, cols] = jnp.where(rect, img + contrib, img)
+        def update(rows, cols, rect):
+            # where-guarded add: pixels outside the rectangle are
+            # untouched (no +0.0), and per-pixel adds run in BFS leaf
+            # order — the same float accumulation sequence as the host
+            # reducer
+            img = img_ref[rows, cols]
+            img_ref[rows, cols] = jnp.where(rect, img + contrib, img)
 
-            _paint_tiles(u0_ref[0, i], v0_ref[0, i], px_ref[0, i], update,
-                         resolution=resolution)
-        return 0
+        _paint_tiles(u0_ref[0, i], v0_ref[0, i], px_ref[0, i], update,
+                     resolution=resolution)
 
-    jax.lax.fori_loop(0, block_n, body, 0)
+    _walk_rows(nxt_ref, paint, block_n=block_n)
 
 
-def _proj_kernel(u0_ref, v0_ref, px_ref, contrib_ref, ok_ref, img_ref, *,
+def _proj_kernel(u0_ref, v0_ref, px_ref, contrib_ref, nxt_ref, img_ref, *,
                  block_n: int, resolution: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         img_ref[...] = jnp.zeros((resolution, resolution), img_ref.dtype)
 
-    _proj_body(u0_ref, v0_ref, px_ref, contrib_ref, ok_ref, img_ref,
+    _proj_body(u0_ref, v0_ref, px_ref, contrib_ref, nxt_ref, img_ref,
                block_n=block_n, resolution=resolution)
 
 
-def _proj_carry_kernel(u0_ref, v0_ref, px_ref, contrib_ref, ok_ref,
+def _proj_carry_kernel(u0_ref, v0_ref, px_ref, contrib_ref, nxt_ref,
                        img0_ref, img_ref, *, block_n: int, resolution: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         img_ref[...] = img0_ref[...]
 
-    _proj_body(u0_ref, v0_ref, px_ref, contrib_ref, ok_ref, img_ref,
+    _proj_body(u0_ref, v0_ref, px_ref, contrib_ref, nxt_ref, img_ref,
                block_n=block_n, resolution=resolution)
 
 
@@ -411,7 +474,7 @@ def projection_raster(u0, v0, px, contrib, ok, *, resolution: int,
                                        contrib.dtype),
         name="projection_raster",
         interpret=interpret,
-    )(u0, v0, px, contrib, ok)
+    )(u0, v0, px, contrib, next_painting_rows(ok, block_n))
 
 
 @functools.partial(jax.jit, static_argnames=("resolution", "block_n",
@@ -440,7 +503,7 @@ def projection_raster_carry(u0, v0, px, contrib, ok, img0, *,
                                        contrib.dtype),
         name="projection_raster_carry",
         interpret=interpret,
-    )(u0, v0, px, contrib, ok, img0)
+    )(u0, v0, px, contrib, next_painting_rows(ok, block_n), img0)
 
 
 # -------------------------------------------------------- histogram kernel

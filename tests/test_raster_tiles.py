@@ -1,13 +1,14 @@
 """Tile-window raster kernels: bit parity with the ref twins and a painter.
 
 The slice and projection kernels paint each leaf only into the (8, 128)
-image tiles its rectangle covers, and skip rows whose ``ok`` is 0
-(DESIGN.md §14). Per pixel the same leaves update it in the same order
-with the same operation as a whole-image pass, so the results must be
-bit for bit those of the ``ref`` twins (through ``ops``, BFS-ordered
-tables, whole and tiled with a carry) and of a plain numpy painter that
-walks the rows one by one (the kernels themselves, any row order, any
-seed). Runs in the Pallas interpreter on the CPU.
+image tiles its rectangle covers, and walk only the rows whose ``ok`` is
+set, by a block-relative next-painting-row column (DESIGN.md §14). Per
+pixel the same leaves update it in the same order with the same
+operation as a whole-image pass, so the results must be bit for bit
+those of the ``ref`` twins (through ``ops``, BFS-ordered tables, whole
+and tiled with a carry; dense, sparse and empty) and of a plain numpy
+painter that walks the rows one by one (the kernels themselves, any row
+order, any seed). Runs in the Pallas interpreter on the CPU.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -89,6 +90,61 @@ def test_ops_pallas_equals_ref(kind, tile_n, resolution):
             _assert_bits(a, b)
     else:
         _assert_bits(got["pallas_interpret"], got["ref"])
+
+
+def _sparse_table(kind: str, resolution: int, n: int = N):
+    """A BFS table whose kernels walk few rows, or none.
+
+    ``sparse``: ``ok`` on ~1% of rows, the first and last row of every
+    block among them. ``few_hits``: ``ok`` on every row but the slice
+    plane through six of them (the projection still paints them all).
+    ``empty``: no ``ok`` row.
+    """
+    (coords, levels, values, ok), n_levels = _bfs_table(7 + resolution,
+                                                        resolution, n)
+    coords, levels = np.array(coords), np.maximum(np.array(levels), 1)
+    rng = np.random.default_rng(resolution)
+    cells = rk.plane_cells(POSITION, n_levels)
+    if kind == "sparse":
+        ok = rng.random(n) < 0.01
+        ok[::ops.BLOCK_N] = ok[ops.BLOCK_N - 1::ops.BLOCK_N] = True
+    elif kind == "few_hits":
+        ok = np.ones(n, bool)
+        coords[:, 2] = cells[levels] ^ 1        # a neighbour cell: a miss
+        hits = rng.choice(n, 6, replace=False)
+        coords[hits, 2] = cells[levels[hits]]
+    else:
+        ok = np.zeros(n, bool)
+    return (jnp.asarray(coords), jnp.asarray(levels), values,
+            jnp.asarray(ok)), n_levels
+
+
+@pytest.mark.parametrize("table", ("sparse", "few_hits", "empty"))
+@pytest.mark.parametrize("resolution", (32, 512))
+@pytest.mark.parametrize("kind,tile_n", OPS_CASES)
+def test_ops_pallas_equals_ref_sparse(kind, tile_n, resolution, table):
+    """Few painting rows, or none: the walk skips the rest bit for bit.
+
+    An empty table leaves the all-NaN slice (depth -1) and the all-zero
+    projection.
+    """
+    arrays, n_levels = _sparse_table(table, resolution)
+    got = {b: _run(kind, b, arrays, resolution, n_levels, tile_n)
+           for b in ("pallas_interpret", "ref")}
+    pairs = (zip(got["pallas_interpret"], got["ref"])
+             if kind == "slice_partial"
+             else [(got["pallas_interpret"], got["ref"])])
+    for a, b in pairs:
+        _assert_bits(a, b)
+    if table == "empty":
+        r = resolution
+        want = {"slice": [np.full((r, r), np.nan, np.float32)],
+                "slice_partial": [np.full((r, r), np.nan, np.float32),
+                                  np.full((r, r), -1, np.int32)]}.get(
+            kind, [np.zeros((r, r), np.float32)])
+        outs = got["pallas_interpret"]
+        for a, b in zip(outs if kind == "slice_partial" else [outs], want):
+            _assert_bits(a, b)
 
 
 # --------------------------------------------- the kernels against a painter
@@ -208,20 +264,22 @@ def test_wide_leaves_cross_tiles(name, px):
 
 # ------------------------------------------------------- engagement counter
 
+#                px   u0   v0   ok   tiles
+FOOTPRINT_ROWS = [(64, 0, 0, 1),        # 8 row tiles x 1 lane block = 8
+                  (64, 64, 192, 1),     # 8 x 1 = 8
+                  (8, 8, 128, 1),       # one tile
+                  (4, 12, 4, 1),        # inside one tile
+                  (1, 511, 511, 1),     # one pixel, one tile
+                  (2, 6, 126, 1),       # one 2x2 leaf in one tile
+                  (128, 128, 256, 1),   # 16 x 1 = 16
+                  (256, 256, 0, 1),     # 32 x 2 = 64
+                  (64, 0, 0, 0),        # ok == 0: not counted
+                  (1, 600, 0, 1)]       # outside the image: a row, no tile
+
+
 def test_footprint_tiles_counts_rows_and_tiles():
     """``ok`` rows counted once; each rectangle's (8, 128) tiles exactly."""
-    #            px   u0   v0   ok   tiles
-    rows = [(64, 0, 0, 1),        # 8 row tiles x 1 lane block = 8
-            (64, 64, 192, 1),     # 8 x 1 = 8
-            (8, 8, 128, 1),       # one tile
-            (4, 12, 4, 1),        # inside one tile
-            (1, 511, 511, 1),     # one pixel, one tile
-            (2, 6, 126, 1),       # one 2x2 leaf in one tile (aligned)
-            (128, 128, 256, 1),   # 16 x 1 = 16
-            (256, 256, 0, 1),     # 32 x 2 = 64
-            (64, 0, 0, 0),        # ok == 0: not counted
-            (1, 600, 0, 1)]       # outside the image: a row, no tile
-    px, u0, v0, ok = (np.array(c, np.int32) for c in zip(*rows))
+    px, u0, v0, ok = (np.array(c, np.int32) for c in zip(*FOOTPRINT_ROWS))
     painting, tiles = rk.footprint_tiles(u0, v0, px, ok, 512)
     assert painting == 9
     assert tiles == 8 + 8 + 1 + 1 + 1 + 1 + 16 + 64
@@ -229,3 +287,77 @@ def test_footprint_tiles_counts_rows_and_tiles():
     assert rk.footprint_tiles(np.array([0, 8]), np.array([0, 16]),
                               np.array([32, 8]), np.array([1, 1]),
                               32) == (2, 4 + 1)
+
+
+def test_walk_trips_equal_painting_rows():
+    """The kernels' loop trips are the painting rows: 9 of 10, and none
+    for the ``ok`` 0 row or the 502 padding rows of the block."""
+    px, u0, v0, ok = (np.array(c, np.int32) for c in zip(*FOOTPRINT_ROWS))
+    padded = ops._pad_leaf(jnp.asarray(ok), 0, rk.DEFAULT_BLOCK_N)
+    walked = rk.walked_rows(rk.next_painting_rows(padded, rk.DEFAULT_BLOCK_N),
+                            rk.DEFAULT_BLOCK_N)
+    assert len(walked) == rk.footprint_tiles(u0, v0, px, ok, 512)[0] == 9
+    np.testing.assert_array_equal(walked, np.flatnonzero(ok))
+    assert 8 not in walked and walked.max() < len(ok)
+
+
+# ------------------------------------------------ next-painting-row column
+
+def _next_rows_np(ok, block_n: int) -> np.ndarray:
+    """Per row, the next row of its block at or after it whose ``ok`` is
+    set (``block_n`` if none), by a backward scan of each block."""
+    ok = np.asarray(ok).reshape(-1, block_n) != 0
+    out = np.empty(ok.shape, np.int32)
+    for b, row in enumerate(ok):
+        nxt = block_n
+        for i in range(block_n - 1, -1, -1):
+            nxt = i if row[i] else nxt
+            out[b, i] = nxt
+    return out.reshape(1, -1)
+
+
+OK_PATTERNS = {                     # block_n = 8
+    "all_zero": [0] * 8,
+    "all_one": [1] * 8,
+    "first_only": [1] + [0] * 7,
+    "last_only": [0] * 7 + [1],
+    "mixed": [0, 1, 1, 0, 0, 1, 0, 0],
+    "padded": [1, 0, 1, 0, 0, 1, 0, 1, 1, 0, 1],   # 5 padding rows
+}
+
+
+@pytest.mark.parametrize("pattern", sorted(OK_PATTERNS))
+def test_next_painting_rows_hand_built(pattern):
+    """The column against a backward scan, and its walk against ``ok``."""
+    ok = np.array(OK_PATTERNS[pattern], np.int32)
+    padded = ops._pad_leaf(jnp.asarray(ok), 0, 8)
+    nxt = np.asarray(rk.next_painting_rows(padded, 8))
+    assert nxt.dtype == np.int32 and nxt.shape == padded.shape
+    np.testing.assert_array_equal(nxt, _next_rows_np(padded, 8))
+    np.testing.assert_array_equal(rk.walked_rows(nxt, 8),
+                                  np.flatnonzero(ok))
+    if pattern == "all_zero":
+        assert (nxt == 8).all()
+    if pattern == "all_one":
+        np.testing.assert_array_equal(nxt[0], np.arange(8))
+    if pattern == "padded":
+        assert (nxt[0, len(ok):] == 8).all()
+
+
+@pytest.mark.parametrize("block_n", (8, rk.DEFAULT_BLOCK_N))
+def test_next_painting_rows_cut_at_tile_boundary(block_n):
+    """Block-relative: the column of a two-block table, cut where a
+    ``tile_n = block_n`` carry cuts it, is the column of each tile."""
+    rng = np.random.default_rng(block_n)
+    ok = (rng.random(2 * block_n) < 0.3).astype(np.int32)
+    ok[block_n - 1] = 0                  # the first block ends bare
+    ok[block_n] = 1                      # the second starts painting
+    whole = np.asarray(rk.next_painting_rows(jnp.asarray(ok)[None], block_n))
+    np.testing.assert_array_equal(whole, _next_rows_np(ok, block_n))
+    for t in range(2):
+        cut = jnp.asarray(ok[t * block_n:(t + 1) * block_n])[None]
+        np.testing.assert_array_equal(
+            np.asarray(rk.next_painting_rows(cut, block_n))[0],
+            whole[0, t * block_n:(t + 1) * block_n])
+    np.testing.assert_array_equal(rk.walked_rows(whole, block_n),
+                                  np.flatnonzero(ok))
